@@ -58,6 +58,8 @@ def main() -> int:
         print(f"[bench] unknown suite(s): {', '.join(unknown)}; "
               f"known: {', '.join(sorted(known))}", file=sys.stderr)
         return 2
+    from repro.utils import init_compile_cache
+    init_compile_cache()
     failed = []
     entries: dict = {}
     t_all = time.perf_counter()

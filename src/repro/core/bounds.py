@@ -77,7 +77,11 @@ def _gemm_bounds(table: jax.Array, qmap: jax.Array, scale: jax.Array,
     if use_kernel:
         from repro.kernels.segment_bound import ops as sb_ops
         return sb_ops.segment_bound_gemm(table, qmap, scale)
-    return jnp.einsum("sv,qv->qs", table.astype(jnp.float32), qmap) * scale
+    # a full f32 contraction: at TPU default precision this GEMM rounds
+    # its operands to bf16 (measured ~1e-3 off on a v5e), and a bound
+    # rounded low stops dominating the scores it must
+    return jnp.einsum("sv,qv->qs", table.astype(jnp.float32), qmap,
+                      precision="highest") * scale
 
 
 def cluster_bounds(index: ClusterIndex, queries: QueryBatch,

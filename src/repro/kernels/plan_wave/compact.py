@@ -36,10 +36,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils import pallas_interpret_default, pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
+from repro.utils import pallas_interpret_default
 
 
 def compact_front(keep: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -67,22 +66,27 @@ def compact_front(keep: jax.Array) -> tuple[jax.Array, jax.Array]:
     return idx.reshape(*lead, n), count.reshape(lead)
 
 
+def _iota_f32(shape, dim):
+    """f32 iota (Mosaic builds iotas in integer types only)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(
+        jnp.float32)
+
+
 def _compact_kernel(keep_ref, idx_ref, count_ref):
     """One (BR, N) row block: tri-matmul cumsum + broadcast-compare."""
     k = keep_ref[:].astype(jnp.float32)                    # (BR, N)
     br, n = k.shape
-    p_col = jax.lax.broadcasted_iota(jnp.float32, (n, n), 0)
-    tri = (p_col <= jax.lax.broadcasted_iota(
-        jnp.float32, (n, n), 1)).astype(jnp.float32)
+    tri = (_iota_f32((n, n), 0) <= _iota_f32((n, n), 1)).astype(
+        jnp.float32)
     cs = jnp.dot(k, tri, preferred_element_type=jnp.float32)  # inclusive
     count = cs[:, -1:]                                     # (BR, 1)
     rank = cs - 1.0
-    s = jax.lax.broadcasted_iota(jnp.float32, (br, n), 1)
-    clamp = jnp.minimum(s, jnp.maximum(count - 1.0, 0.0))  # (BR, N)
+    clamp = jnp.minimum(_iota_f32((br, n), 1),
+                        jnp.maximum(count - 1.0, 0.0))     # (BR, N)
     # scatter-free index build: slot s takes the position whose rank
     # equals the clamped slot (unique per row among kept entries)
     match = (k[:, :, None] > 0.0) & (rank[:, :, None] == clamp[:, None, :])
-    p = jax.lax.broadcasted_iota(jnp.float32, (br, n, n), 1)
+    p = _iota_f32((br, n, n), 1)
     idx_ref[:] = jnp.where(match, p, 0.0).sum(axis=1).astype(jnp.int32)
     count_ref[:] = count.astype(jnp.int32)
 
@@ -112,7 +116,7 @@ def compact_front_pallas(keep: jax.Array, block_rows: int = 8,
                    pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows_p, n_p), jnp.int32),
                    jax.ShapeDtypeStruct((rows_p, 1), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(kp)

@@ -7,8 +7,8 @@ applies scale and the planner's doc-admission mask so every non-admitted
 (query, doc) pair — including grid blocks the compacted queues never
 visited — comes out exactly ``NEG``.
 
-Interpret mode is auto-detected per call (compiled on TPU, interpreted
-elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides) — see
+Interpret mode is resolved per call (always compiled on a TPU,
+interpreted elsewhere unless ``REPRO_PALLAS_INTERPRET=0``) — see
 ``repro.utils.pallas_interpret_default``.
 """
 

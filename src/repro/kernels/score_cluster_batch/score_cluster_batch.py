@@ -55,9 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils import pallas_interpret_default, pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
+from repro.utils import pallas_interpret_default
 
 # python float (not a traced jnp scalar): pallas kernels cannot capture
 # array constants
@@ -105,7 +103,8 @@ def _kernel(tile_cids_ref, tile_pos_ref, n_tiles_ref, qblock_ref,
              & (d < n_dblock_ref[i, j]))
     def _score():
         tids = tids_ref[...][0].astype(jnp.int32)        # (BD, tp)
-        tw = tw_ref[...][0].astype(jnp.float32)          # (BD, tp)
+        # Mosaic has no uint8 -> float32 cast: widen through int32
+        tw = tw_ref[...][0].astype(jnp.int32).astype(jnp.float32)
         qmaps = qmaps_ref[...]                           # (BQ, BV)
         if n_vb == 1:
             qv = jnp.take(qmaps, tids.reshape(-1), axis=1,
@@ -123,21 +122,21 @@ def _kernel(tile_cids_ref, tile_pos_ref, n_tiles_ref, qblock_ref,
         # residual docs the sub-tile carries outside this query block's
         # union: exactly NEG in the written output (unvisited blocks
         # stay garbage; the op wrapper's doc-admission mask owns those)
-        in_run = dmask_ref[...][0, 0] != 0                # (BD,)
+        in_run = dmask_ref[...][0, 0, 0] != 0             # (1, BD)
 
         if n_vb == 1:
-            out_ref[...] = jnp.where(in_run[None], partial_scores,
-                                     NEG)[:, None, :]
+            out_ref[...] = jnp.where(in_run, partial_scores,
+                                     NEG)[None, None, None]
         else:
             @pl.when(k == 0)
             def _init():
-                out_ref[...] = jnp.where(in_run[None], partial_scores,
-                                         NEG)[:, None, :]
+                out_ref[...] = jnp.where(in_run, partial_scores,
+                                         NEG)[None, None, None]
 
             @pl.when(k > 0)
             def _accum():
-                out_ref[...] += jnp.where(in_run[None], partial_scores,
-                                          0.0)[:, None, :]
+                out_ref[...] += jnp.where(in_run, partial_scores,
+                                          0.0)[None, None, None]
 
 
 @functools.partial(
@@ -202,11 +201,19 @@ def score_queue_kernel(
 
     def dmask_idx(i, j, d, k, cids, pos, nt, qb, nqb, db, ndb):
         ii, jj, dd, _ = _queue_step(i, j, d, nt, nqb, ndb)
-        return (ii, jj, db[ii, jj, dd])
+        return (ii, jj, db[ii, jj, dd], 0, 0)
 
     def out_idx(i, j, d, k, cids, pos, nt, qb, nqb, db, ndb):
         ii, jj, dd, _ = _queue_step(i, j, d, nt, nqb, ndb)
-        return (qb[ii, jj], pos[ii], db[ii, jj, dd])
+        return (pos[ii], db[ii, jj, dd], qb[ii, jj], 0, 0)
+
+    # the per-step mask and output blocks are whole trailing dims of
+    # these layouts, which keeps them legal TPU blocks ((8, 128)-aligned
+    # or full) for any block_q / block_d; the output is transposed back
+    # to (n_q_pad, G, dp) below
+    n_qb_out = n_q_pad // block_q
+    dmask5 = dmask_union.astype(jnp.int32).reshape(G, n_qb, n_db, 1,
+                                                   block_d)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
@@ -223,20 +230,20 @@ def score_queue_kernel(
             pl.BlockSpec((block_q, block_v), qmap_idx),
             # per-qblock union doc-admission for the in-kernel residual
             # mask
-            pl.BlockSpec((1, 1, block_d), dmask_idx),
+            pl.BlockSpec((1, 1, 1, 1, block_d), dmask_idx),
         ],
-        out_specs=pl.BlockSpec((block_q, 1, block_d), out_idx),
+        out_specs=pl.BlockSpec((1, 1, 1, block_q, block_d), out_idx),
     )
     out = pl.pallas_call(
         functools.partial(_kernel, n_vb=n_vb, block_v=block_v),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_q_pad, G, dp), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((G, n_db, n_qb_out, block_q, block_d),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 4),
         interpret=interpret,
     )(tile_cids.astype(jnp.int32), tile_pos.astype(jnp.int32),
       n_tiles.reshape(1).astype(jnp.int32), qblock.astype(jnp.int32),
       n_qblock.astype(jnp.int32), dblock.astype(jnp.int32),
-      n_dblock.astype(jnp.int32), doc_tids, doc_tw, qmaps,
-      dmask_union.astype(jnp.uint8))
-    return out
+      n_dblock.astype(jnp.int32), doc_tids, doc_tw, qmaps, dmask5)
+    return out.transpose(2, 3, 0, 1, 4).reshape(n_q_pad, G, dp)

@@ -1,8 +1,8 @@
 """Jit'd public wrapper for the score_docs kernel: accepts the search
 layer's (..., d_pad, t_pad) cluster blocks and flattens them for the grid.
 
-Interpret mode is auto-detected per call (compiled on TPU, interpreted
-elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides) — see
+Interpret mode is resolved per call (always compiled on a TPU,
+interpreted elsewhere unless ``REPRO_PALLAS_INTERPRET=0``) — see
 ``repro.utils.pallas_interpret_default``.
 """
 

@@ -21,14 +21,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils import pallas_interpret_default, pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
+from repro.utils import pallas_interpret_default
 
 
 def _kernel(scale_ref, tids_ref, tw_ref, qmap_ref, out_ref):
     tids = tids_ref[...].astype(jnp.int32)                # (BD, T)
-    tw = tw_ref[...].astype(jnp.float32)                  # (BD, T)
+    # Mosaic has no uint8 -> float32 cast: widen through int32
+    tw = tw_ref[...].astype(jnp.int32).astype(jnp.float32)  # (BD, T)
     qv = jnp.take(qmap_ref[...], tids, axis=0,
                   indices_are_sorted=False, unique_indices=False)
     score = jnp.sum(qv * tw, axis=-1, keepdims=True)      # (BD, 1)
@@ -67,7 +66,7 @@ def score_docs_kernel(
         ],
         out_specs=pl.BlockSpec((block_d, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Dp, 1), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(scale.reshape(1), doc_tids, doc_tw, qmap)

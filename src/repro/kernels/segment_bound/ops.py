@@ -1,7 +1,7 @@
 """Jit'd public wrapper for the segment-bound kernel.
 
-Interpret mode is auto-detected per call (compiled on TPU, interpreted
-elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides) — see
+Interpret mode is resolved per call (always compiled on a TPU,
+interpreted elsewhere unless ``REPRO_PALLAS_INTERPRET=0``) — see
 ``repro.utils.pallas_interpret_default``.
 """
 

@@ -24,9 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils import pallas_interpret_default, pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
+from repro.utils import pallas_interpret_default
 
 
 def _kernel(scale_ref, table_ref, qmap_ref, out_ref, *, n_v: int):
@@ -36,10 +34,13 @@ def _kernel(scale_ref, table_ref, qmap_ref, out_ref, *, n_v: int):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    t = table_ref[...].astype(jnp.float32)          # (BS, BV) dequant u8
+    # Mosaic has no uint8 -> float32 cast: widen through int32
+    t = table_ref[...].astype(jnp.int32).astype(jnp.float32)  # (BS, BV)
     q = qmap_ref[...]                               # (BQ, BV)
     acc = jax.lax.dot_general(
         q, t, (((1,), (1,)), ((), ())),
+        # full f32 contraction: a bound rounded low stops dominating
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)         # (BQ, BS)
     out_ref[...] += acc
 
@@ -65,16 +66,17 @@ def segment_bound_gemm(
         interpret = pallas_interpret_default()
     S, V = table.shape
     Q = qmap.shape[0]
-    s_pad = -S % block_s
+    # the table is never copied (at asc-splade it is ~1.1 GB): its edge
+    # blocks overhang S and V, and the overhang holds unspecified but
+    # finite uint8 values. Only the small query map is padded, with
+    # zeros, so overhanging vocab columns contribute exactly 0 and
+    # overhanging rows land in output columns sliced off below.
     q_pad = -Q % block_q
     v_pad = -V % block_v
-    if s_pad or v_pad:
-        table = jnp.pad(table, ((0, s_pad), (0, v_pad)))
     if q_pad or v_pad:
         qmap = jnp.pad(qmap, ((0, q_pad), (0, v_pad)))
-    Sp, Vp = table.shape
-    Qp = qmap.shape[0]
-    n_s, n_q, n_v = Sp // block_s, Qp // block_q, Vp // block_v
+    Qp, Vp = qmap.shape
+    n_s, n_q, n_v = pl.cdiv(S, block_s), Qp // block_q, Vp // block_v
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_v=n_v),
@@ -85,8 +87,8 @@ def segment_bound_gemm(
             pl.BlockSpec((block_q, block_v), lambda i, j, k: (j, k)),
         ],
         out_specs=pl.BlockSpec((block_q, block_s), lambda i, j, k: (j, i)),
-        out_shape=jax.ShapeDtypeStruct((Qp, Sp), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((Qp, n_s * block_s), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(scale.reshape(1), table, qmap)

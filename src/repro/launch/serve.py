@@ -54,9 +54,15 @@ Observability options (docs/observability.md):
   --split-every N    every Nth request, split planner vs executor wall
                      time into the registry (0 = only traced requests).
 
-With ``--devices N`` the index is sharded over a forced host mesh and
-served through the shard_map selective-search path — the same code that
-runs on the production (pod, data, model) mesh.
+With ``--devices N`` (N even) the index is sharded over an (N/2, 2)
+("data", "model") mesh of N devices and served through the shard_map
+selective-search path — the same code that runs on the production
+(pod, data, model) mesh. The run fails when fewer than N devices exist;
+N virtual host devices are made only where the CPU backend is forced
+(``JAX_PLATFORMS=cpu``).
+
+The run exits non-zero when a streaming front-end batch failed to
+dispatch, so a failing device cannot pass for a served run.
 """
 
 import argparse
@@ -277,7 +283,10 @@ def _recover_writer(eng, args, registry, backoff_cap_s: float = 2.0):
 
 def main() -> None:
     args = _parse()
-    if args.devices:
+    if args.devices % 2:
+        raise SystemExit(f"[serve] --devices must be even (an (N/2, 2) "
+                         f"mesh), got {args.devices}")
+    if args.devices and os.environ.get("JAX_PLATFORMS") == "cpu":
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.devices}")
@@ -286,11 +295,19 @@ def main() -> None:
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.utils import init_compile_cache
+    init_compile_cache()
+    if args.devices and jax.device_count() < args.devices:
+        raise SystemExit(
+            f"[serve] --devices {args.devices} needs {args.devices} "
+            f"{jax.default_backend()} devices, found {jax.device_count()}")
+
     from repro.core.clustering import (balanced_assign,
                                        dense_rep_projection, lloyd_kmeans)
     from repro.core.index import build_index
     from repro.core.search import SearchConfig, retrieve
     from repro.data.synthetic import CorpusSpec, make_corpus, make_queries
+    from repro.launch.mesh import make_host_mesh
     from repro.lifecycle import IndexWriter, load_index, save_index
     from repro.obs import MetricsRegistry, Observability
     from repro.serving.engine import (AdaptiveBudget, RetrievalEngine,
@@ -340,12 +357,11 @@ def main() -> None:
     cfg = SearchConfig(k=args.k, mu=args.mu, eta=args.eta,
                        engine=args.engine)
 
-    if args.devices and jax.device_count() >= 4:
+    if args.devices:
         if args.churn or args.save_dir or args.budget_ms:
             print("[serve] warning: --churn/--save-dir/--budget-ms are "
                   "ignored on the distributed (--devices) path")
-        mesh = jax.make_mesh((jax.device_count() // 2, 2),
-                             ("data", "model"))
+        mesh = make_host_mesh((args.devices // 2, 2))
         ispecs = index_shard_specs(index)
         i_shard = jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), ispecs,
@@ -528,6 +544,13 @@ def main() -> None:
             save_index(args.save_dir, final, epoch=epoch,
                        n_shards=min(4, final.m))
             print(f"[serve] saved epoch {epoch} -> {args.save_dir}")
+
+    if frontend is not None:
+        lost = sum(i.value for i in registry.instruments()
+                   if i.name == "frontend_dispatch_failures_total")
+        if lost:
+            raise SystemExit(f"[serve] {lost:.0f} front-end batch(es) "
+                             f"failed to dispatch")
 
 
 if __name__ == "__main__":

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_rules
-from repro.utils import shard_map
 
 
 def embedding_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
@@ -55,7 +54,7 @@ def embedding_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
 
     ids_spec = P(batch_axes, *([None] * (ids.ndim - 1)))
     out_spec = P(batch_axes, *([None] * ids.ndim))
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=rules.mesh,
         in_specs=(P(axis, None), ids_spec),
         out_specs=out_spec, check_vma=False)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
 from repro.models.layers import dense_init
-from repro.utils import rank_within_run, shard_map
+from repro.utils import rank_within_run
 
 
 @dataclasses.dataclass(frozen=True)
@@ -241,7 +241,7 @@ def _apply_moe_a2a(params: dict, x: jax.Array, gates: jax.Array,
     # weight shards: experts over 'model', input dim FSDP over data axes
     w_spec = P("model", data_axes, None)
     w_gate = params.get("w_gate", params["w_up"])
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(w_spec, w_spec, P("model", None, data_axes),
                   act_spec, k_spec, k_spec),

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import collections
 import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +46,6 @@ from repro.core.types import ClusterIndex, QueryBatch, TopK
 from repro.lifecycle.snapshot import IndexSnapshot, SnapshotPublisher
 from repro.obs.funnel import Observability, funnel_from_topk, record_funnel
 from repro.obs.metrics import (LATENCY_BUCKETS_MS, MetricsRegistry)
-from repro.utils import shard_map
 
 
 class ServeStats:
@@ -612,13 +612,41 @@ def distributed_retrieve(index: ClusterIndex, queries: QueryBatch,
     single-host engine records — the recording is host-side and forces
     a device sync, which the serving callers (launch/serve.py) do
     anyway to time the batch."""
-    caxes = ("pod", "data") if multi_pod else ("data",)
-    qaxis = "model"
     if cfg.superblocks:
         raise ValueError(
             "superblocks=True is not supported on the distributed path: "
             "the replicated coarse tables index global cluster ids, "
             "which a cluster shard's local arrays cannot resolve")
+    out = _distributed_topk(index, queries, cfg, mesh, multi_pod)
+    if registry is not None:
+        # counter semantics are set by the engine each *shard* ran — the
+        # auto route keys on the shard-local batch (queries shard over
+        # the model axis), and each query shard's batched counters are
+        # replicated only within its own sub-batch, so the funnel sums
+        # one representative slot per query shard
+        n_shards = mesh.shape["model"]
+        n_local = queries.n_queries // n_shards
+        batched = resolved_engine(cfg, max(n_local, 1)) in (
+            "batched", "pipelined")
+        m = index.m
+        budget = cfg.cluster_budget if cfg.cluster_budget is not None \
+            else m
+        funnel = funnel_from_topk(
+            out, batched=batched, n_q=queries.n_queries,
+            d_pad=index.d_pad, budget_clusters=min(budget, m),
+            n_query_shards=n_shards)
+        record_funnel(registry, funnel)
+    return out
+
+
+@partial(jax.jit, static_argnames=("cfg", "mesh", "multi_pod"))
+def _distributed_topk(index: ClusterIndex, queries: QueryBatch,
+                      cfg: SearchConfig, mesh, multi_pod: bool) -> TopK:
+    """The shard_map program of :func:`distributed_retrieve`, jitted so
+    that each (cfg, mesh, shapes) compiles once — called eagerly, the
+    shard_map retraced and recompiled its body on every batch."""
+    caxes = ("pod", "data") if multi_pod else ("data",)
+    qaxis = "model"
     ispecs = index_shard_specs(index, multi_pod)
     qspec = QueryBatch(tids=P(qaxis, None), tw=P(qaxis, None),
                        mask=P(qaxis, None), vocab=queries.vocab)
@@ -661,25 +689,6 @@ def distributed_retrieve(index: ClusterIndex, queries: QueryBatch,
                      n_bounded_clusters=P(qaxis),
                      n_walked_superblocks=P(qaxis),
                      n_pruned_superblocks=P(qaxis))
-    fn = shard_map(local, mesh=mesh, in_specs=(ispecs, qspec),
-                   out_specs=out_specs, check_vma=False)
-    out = fn(index, queries)
-    if registry is not None:
-        # counter semantics are set by the engine each *shard* ran — the
-        # auto route keys on the shard-local batch (queries shard over
-        # the model axis), and each query shard's batched counters are
-        # replicated only within its own sub-batch, so the funnel sums
-        # one representative slot per query shard
-        n_shards = mesh.shape[qaxis]
-        n_local = queries.n_queries // n_shards
-        batched = resolved_engine(cfg, max(n_local, 1)) in (
-            "batched", "pipelined")
-        m = index.m
-        budget = cfg.cluster_budget if cfg.cluster_budget is not None \
-            else m
-        funnel = funnel_from_topk(
-            out, batched=batched, n_q=queries.n_queries,
-            d_pad=index.d_pad, budget_clusters=min(budget, m),
-            n_query_shards=n_shards)
-        record_funnel(registry, funnel)
-    return out
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(ispecs, qspec),
+                       out_specs=out_specs, check_vma=False)
+    return fn(index, queries)

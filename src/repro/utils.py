@@ -38,34 +38,42 @@ def tree_size_bytes(tree) -> int:
                if hasattr(x, "dtype"))
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map: ``jax.shard_map`` (new API) when
-    available, else ``jax.experimental.shard_map`` with the old
-    ``check_rep`` spelling of ``check_vma``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def pallas_interpret_default() -> bool:
     """Whether Pallas kernels should run in interpret mode here.
 
-    ``REPRO_PALLAS_INTERPRET`` wins when set ("0" => compiled, anything
-    else => interpret); otherwise auto-detect: compile on TPU, interpret
-    everywhere else (the kernels are written for Mosaic — off-TPU the
-    Python interpreter is the only backend that runs them).
+    On a TPU backend kernels always compile: ``REPRO_PALLAS_INTERPRET``
+    may be unset or "0" there, and any other value raises instead of
+    quietly timing the Python interpreter on the chip. Off the TPU the
+    kernels (written for Mosaic) run in interpret mode, which the
+    variable set to "0" turns off.
     """
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    return jax.default_backend() != "tpu"
+    if jax.default_backend() == "tpu":
+        if env not in (None, "0"):
+            raise RuntimeError(
+                f"REPRO_PALLAS_INTERPRET={env!r} asks for interpret mode "
+                f"on a TPU backend; Pallas kernels compile on the TPU "
+                f"(unset it or set it to '0')")
+        return False
+    return env != "0"
 
 
-def pallas_tpu_compiler_params():
-    """Version-portable Pallas TPU CompilerParams class (jax renamed
-    TPUCompilerParams -> CompilerParams across releases)."""
-    from jax.experimental.pallas import tpu as pltpu
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+#: persistent compile cache of a checkout that sets no
+#: ``JAX_COMPILATION_CACHE_DIR``: a fixed path, since the path is part of
+#: the cache key (git-ignored)
+CHECKOUT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Called at the start of ``main``, never on
+    import. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    itself and this sets no other directory; otherwise the cache lives
+    at :data:`CHECKOUT_COMPILE_CACHE`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_COMPILE_CACHE)
+    return CHECKOUT_COMPILE_CACHE

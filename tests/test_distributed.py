@@ -95,7 +95,8 @@ step = make_train_step(lambda p, b: tf.loss_fn(p, b, cfg), optimizer,
 # single device
 p1, o1, m1 = jax.jit(step)(params, opt_state, batch, jnp.int32(0))
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = sh.lm_rules(mesh, training=True)
 with mesh, sh.use_rules(rules):
     p_shard = _shardings(rules, tf.param_axes(cfg), params)
@@ -151,10 +152,9 @@ def body(g):
     mean, ef = compressed_mean(grads, None, axis="pod")
     return mean["w"], ef["w"]
 
-from repro.utils import shard_map
-fn = shard_map(body, mesh=mesh,
-               in_specs=P("pod", None), out_specs=P(None),
-               check_vma=False)
+fn = jax.shard_map(body, mesh=mesh,
+                   in_specs=P("pod", None), out_specs=P(None),
+                   check_vma=False)
 with mesh:
     mean, ef = fn(g_global)
 expected = np.asarray(g_global.mean(0))
@@ -209,7 +209,8 @@ p = moe_lib.moe_init(jax.random.PRNGKey(0), D, cfg, "swiglu", jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, D))
 ref, aux_ref = moe_lib.apply_moe(p, x, cfg, "swiglu")   # no mesh: reference
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = sh.lm_rules(mesh, training=True)
 with mesh, sh.use_rules(rules):
     assert moe_lib._a2a_path_available(cfg, 4, 16)
@@ -243,7 +244,8 @@ def loss(p, x):
     return jnp.sum(y.astype(jnp.float32) ** 2) + aux
 
 g_ref = jax.grad(loss)(p, x)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = sh.lm_rules(mesh, training=True)
 with mesh, sh.use_rules(rules):
     g = jax.jit(jax.grad(loss))(p, x)

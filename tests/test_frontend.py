@@ -560,3 +560,29 @@ def test_service_model_overrides_clock_charge(engine, rows):
     assert fe.clock.now() == pytest.approx(7e-3)
     for f in futs:
         assert f.result(timeout=0).latency_ms == pytest.approx(7.0)
+
+
+def test_serve_launcher_exits_nonzero_on_dispatch_failure(monkeypatch):
+    """A front-end batch the engine failed to serve ends a
+    ``launch/serve.py`` run with a non-zero exit, so a failing device
+    cannot pass for a served run."""
+    import signal
+    import sys
+
+    from repro import utils
+    from repro.launch import serve
+    from repro.serving.engine import RetrievalEngine
+
+    def lost_device(self, *a, **kw):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(RetrievalEngine, "search", lost_device)
+    monkeypatch.setattr(utils, "init_compile_cache", lambda: "")
+    monkeypatch.setattr(signal, "signal", lambda *a: None)
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--n-docs", "300", "--vocab", "128", "--clusters", "4",
+        "--segments", "2", "--batches", "1", "--batch-size", "4",
+        "--frontend", "closed"])
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert "failed to dispatch" in str(exc.value.code)

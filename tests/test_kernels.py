@@ -7,6 +7,8 @@ geometry, including the ragged/padded edges.
 
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -194,3 +196,48 @@ def test_kernel_bounds_match_gather_in_search(index, queries):
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(b_kernel), np.asarray(b_gemm),
                                rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# interpret-mode policy and the compile cache
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,env,want", [
+    ("tpu", None, False), ("tpu", "0", False), ("tpu", "1", "raises"),
+    ("tpu", "true", "raises"), ("cpu", None, True), ("cpu", "1", True),
+    ("cpu", "0", False)])
+def test_pallas_interpret_default_never_interprets_on_tpu(
+        monkeypatch, backend, env, want):
+    from repro import utils
+    monkeypatch.setattr(utils.jax, "default_backend", lambda: backend)
+    if env is None:
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", env)
+    if want == "raises":
+        with pytest.raises(RuntimeError, match="TPU"):
+            utils.pallas_interpret_default()
+    else:
+        assert utils.pallas_interpret_default() is want
+
+
+@pytest.mark.parametrize("env", [None, "/some/shared/cache"])
+def test_init_compile_cache_honours_env_else_checkout(monkeypatch, env):
+    from repro import utils
+    before = jax.config.jax_compilation_cache_dir
+    calls = []
+    monkeypatch.setattr(utils.jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert utils.init_compile_cache() == utils.CHECKOUT_COMPILE_CACHE
+        assert calls == [("jax_compilation_cache_dir",
+                          utils.CHECKOUT_COMPILE_CACHE)]
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert utils.CHECKOUT_COMPILE_CACHE == os.path.join(root,
+                                                            ".jax_cache")
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        assert utils.init_compile_cache() == env
+        assert calls == []
+    assert jax.config.jax_compilation_cache_dir == before
