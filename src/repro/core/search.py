@@ -89,6 +89,14 @@ from repro.kernels.score_cluster_batch.ref import (SCORE_CHUNK,
 
 NEG = jnp.float32(jnp.finfo(jnp.float32).min)
 
+#: named scopes of the served step's phases. Every leaf op of a compiled
+#: ``retrieve`` carries one of them in its ``op_name`` metadata, so a
+#: device trace splits the step's time by phase (docs/observability.md
+#: §traces): the bounds pass, the planner (visitation order and each
+#: wave's admission and queues), the executor, and the top-k merge with
+#: its counters and early-exit test.
+PHASE_SCOPES = ("asc.bounds", "asc.plan", "asc.execute", "asc.merge")
+
 
 # `engine="auto"` routes tiny batches to the per-query reference engine:
 # below this batch size the batched planner's per-wave queue compaction
@@ -331,6 +339,7 @@ def brute_force_topk(index: ClusterIndex, queries: QueryBatch,
         n_scored_segments=jnp.full((nq,), index.m * index.n_seg, jnp.int32),
         n_scored_tiles=m_full, n_walked_tiles=m_full,
         n_walked_docs=jnp.full((nq,), index.m * index.d_pad, jnp.int32),
+        n_waves=jnp.zeros((nq,), jnp.int32),
         n_bounded_clusters=m_full,
         n_walked_superblocks=jnp.full((nq,), index.n_super, jnp.int32),
         n_pruned_superblocks=jnp.zeros((nq,), jnp.int32),
@@ -368,10 +377,11 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
     k = cfg.k
     n_seg_eff = seg_b.shape[1]
 
-    order = jnp.argsort(-order_key)                          # (m,)
-    order = jnp.pad(order, (0, m_padded - m))
-    sorted_key = jnp.pad(jnp.sort(-order_key) * -1.0,
-                         (0, m_padded - m), constant_values=NEG)
+    with jax.named_scope("asc.plan"):
+        order = jnp.argsort(-order_key)                      # (m,)
+        order = jnp.pad(order, (0, m_padded - m))
+        sorted_key = jnp.pad(jnp.sort(-order_key) * -1.0,
+                             (0, m_padded - m), constant_values=NEG)
     # work-based budget (the paper's time-budget semantics): only clusters
     # actually *scored* consume budget — clusters skipped by the (mu, eta)
     # test are free, so tighter pruning stretches the same budget deeper
@@ -389,59 +399,65 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
 
     def cond(state):
         g, done, *_ = state
-        return jnp.logical_and(g < n_groups, jnp.logical_not(done))
+        with jax.named_scope("asc.merge"):
+            return jnp.logical_and(g < n_groups, jnp.logical_not(done))
 
     def body(state):
         g, done, top_scores, top_ids, n_docs, n_clusters, n_segments = state
-        theta = top_scores[k - 1]
-        pos = g * G
-        cids = jax.lax.dynamic_slice(order, (pos,), (G,))     # (G,)
-        gkey = jax.lax.dynamic_slice(sorted_key, (pos,), (G,))
-        live = (jnp.arange(G) + pos < m) & (gkey > NEG)
+        with jax.named_scope("asc.plan"):
+            theta = top_scores[k - 1]
+            pos = g * G
+            cids = jax.lax.dynamic_slice(order, (pos,), (G,))  # (G,)
+            gkey = jax.lax.dynamic_slice(sorted_key, (pos,), (G,))
+            live = (jnp.arange(G) + pos < m) & (gkey > NEG)
 
-        b = seg_b[cids]                                       # (G, n_seg)
-        if cfg.method == "asc":
-            pruned = (max_s[cids] <= theta / mu) & (avg_s[cids] <= theta / eta)
-        else:
-            pruned = gkey <= theta / mu
-        admit = live & jnp.logical_not(pruned)                # (G,)
-        # spend budget only on admitted clusters, in visitation order
-        admit = admit & (n_clusters + jnp.cumsum(admit.astype(jnp.int32))
-                         <= budget)
+            b = seg_b[cids]                                    # (G, n_seg)
+            if cfg.method == "asc":
+                pruned = ((max_s[cids] <= theta / mu)
+                          & (avg_s[cids] <= theta / eta))
+            else:
+                pruned = gkey <= theta / mu
+            admit = live & jnp.logical_not(pruned)             # (G,)
+            # spend budget only on admitted clusters, in visitation order
+            admit = admit & (n_clusters
+                             + jnp.cumsum(admit.astype(jnp.int32))
+                             <= budget)
 
-        # segment-level document pruning: B_ij is a valid upper bound for
-        # every doc in segment j (Prop 1 proof), over-estimated by eta (ASC)
-        # / mu (Anytime*).
-        if cfg.doc_prune:
-            seg_admit = b > theta / (eta if cfg.method == "asc" else mu)
-        else:
-            seg_admit = jnp.ones_like(b, dtype=bool)
-        seg_admit = seg_admit & admit[:, None]                # (G, n_seg)
+            # segment-level document pruning: B_ij is a valid upper bound
+            # for every doc in segment j (Prop 1 proof), over-estimated by
+            # eta (ASC) / mu (Anytime*).
+            if cfg.doc_prune:
+                seg_admit = b > theta / (eta if cfg.method == "asc" else mu)
+            else:
+                seg_admit = jnp.ones_like(b, dtype=bool)
+            seg_admit = seg_admit & admit[:, None]             # (G, n_seg)
 
-        scores = _score_docs(index, cids, qmap, cfg)          # (G, d_pad)
-        if n_seg_eff == 1:      # collapsed (anytime) segment table
-            seg_ok = seg_admit[:, :1]                         # (G, 1)
-        else:                   # hoisted pre-modded map: no per-wave mod
-            seg_ok = jnp.take_along_axis(
-                seg_admit, index.doc_seg_mod[cids], axis=1)
-        doc_admit = index.doc_mask[cids] & seg_ok
-        scores = jnp.where(doc_admit, scores, NEG)
+        with jax.named_scope("asc.execute"):
+            scores = _score_docs(index, cids, qmap, cfg)       # (G, d_pad)
+            if n_seg_eff == 1:      # collapsed (anytime) segment table
+                seg_ok = seg_admit[:, :1]                      # (G, 1)
+            else:                   # hoisted pre-modded map: no per-wave mod
+                seg_ok = jnp.take_along_axis(
+                    seg_admit, index.doc_seg_mod[cids], axis=1)
+            doc_admit = index.doc_mask[cids] & seg_ok
+            scores = jnp.where(doc_admit, scores, NEG)
 
-        cand_scores = jnp.concatenate([top_scores, scores.reshape(-1)])
-        cand_ids = jnp.concatenate([top_ids,
-                                    index.doc_ids[cids].reshape(-1)])
-        top_scores, pos_k = jax.lax.top_k(cand_scores, k)
-        top_ids = cand_ids[pos_k]
+        with jax.named_scope("asc.merge"):
+            cand_scores = jnp.concatenate([top_scores, scores.reshape(-1)])
+            cand_ids = jnp.concatenate([top_ids,
+                                        index.doc_ids[cids].reshape(-1)])
+            top_scores, pos_k = jax.lax.top_k(cand_scores, k)
+            top_ids = cand_ids[pos_k]
 
-        n_docs += doc_admit.sum().astype(jnp.int32)
-        n_clusters += admit.sum().astype(jnp.int32)
-        n_segments += seg_admit.sum().astype(jnp.int32)
+            n_docs += doc_admit.sum().astype(jnp.int32)
+            n_clusters += admit.sum().astype(jnp.int32)
+            n_segments += seg_admit.sum().astype(jnp.int32)
 
-        theta_new = top_scores[k - 1]
-        nxt = jnp.minimum((g + 1) * G, m_padded - 1)
-        done = sorted_key[nxt] <= theta_new / exit_div
-        # budget exhaustion also terminates
-        done = jnp.logical_or(done, n_clusters >= budget)
+            theta_new = top_scores[k - 1]
+            nxt = jnp.minimum((g + 1) * G, m_padded - 1)
+            done = sorted_key[nxt] <= theta_new / exit_div
+            # budget exhaustion also terminates
+            done = jnp.logical_or(done, n_clusters >= budget)
         return (g + 1, done, top_scores, top_ids,
                 n_docs, n_clusters, n_segments)
 
@@ -450,14 +466,16 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
             jnp.int32(0), jnp.int32(0), jnp.int32(0))
     (g_end, _, top_scores, top_ids, n_docs, n_clusters, n_segments) = (
         jax.lax.while_loop(cond, body, init))
-    top_ids = jnp.where(top_scores > NEG, top_ids, -1)
-    # tile counters in per-query terms (see TopK docstring): every
-    # admitted cluster is a scored tile, every visited cluster position
-    # a walked one (clamped: the last group's padding is not a cluster);
-    # whole-tile execution walks exactly d_pad doc slots per scored tile
-    return (top_ids, top_scores, n_docs, n_clusters, n_segments,
-            n_clusters, jnp.minimum(g_end * G, jnp.int32(m)),
-            n_clusters * jnp.int32(index.d_pad))
+    with jax.named_scope("asc.merge"):
+        top_ids = jnp.where(top_scores > NEG, top_ids, -1)
+        # tile counters in per-query terms (see TopK docstring): every
+        # admitted cluster is a scored tile, every visited cluster
+        # position a walked one (clamped: the last group's padding is
+        # not a cluster); whole-tile execution walks exactly d_pad doc
+        # slots per scored tile
+        return (top_ids, top_scores, n_docs, n_clusters, n_segments,
+                n_clusters, jnp.minimum(g_end * G, jnp.int32(m)),
+                n_clusters * jnp.int32(index.d_pad), g_end)
 
 
 def _admission(cfg: SearchConfig, *, glive, done, theta, max_s_w, avg_s_w,
@@ -569,30 +587,93 @@ def _execute_wave(index: ClusterIndex, plan: WavePlan, qmaps: jax.Array,
     default to gathering from ``plan.cids`` — inside the search loop the
     identical gathers already exist in the planner's trace and XLA CSE
     dedupes them; replay callers (execute_plans) rely on the defaults."""
-    if dseg_mod is None:
-        dseg_mod = index.doc_seg_mod[plan.cids]             # (G, dp)
-    if dmask is None:
-        dmask = index.doc_mask[plan.cids]
-    if cfg.use_kernel:
-        from repro.kernels.score_cluster_batch import ops as scb_ops
-        block_v = resolve_blocks(index, qmaps.shape[0], cfg)[2]
-        return scb_ops.score_admitted(
-            index.doc_tids, index.doc_tw, dseg_mod, dmask, qmaps, plan,
-            index.scale, block_v=block_v)
+    with jax.named_scope("asc.execute"):
+        if dseg_mod is None:
+            dseg_mod = index.doc_seg_mod[plan.cids]         # (G, dp)
+        if dmask is None:
+            dmask = index.doc_mask[plan.cids]
+        if cfg.use_kernel:
+            from repro.kernels.score_cluster_batch import ops as scb_ops
+            block_v = resolve_blocks(index, qmaps.shape[0], cfg)[2]
+            return scb_ops.score_admitted(
+                index.doc_tids, index.doc_tw, dseg_mod, dmask, qmaps, plan,
+                index.scale, block_v=block_v)
 
-    def dense(_):
-        tids = index.doc_tids[plan.cids]                    # (G, dp, tp)
-        tw = index.doc_tw[plan.cids]
-        return score_admitted_ref(tids, tw, dseg_mod, dmask, qmaps, plan,
-                                  index.scale,
-                                  impl=resolve_score_impl(
-                                      cfg, qmaps.shape[0]))
+        def dense(_):
+            tids = index.doc_tids[plan.cids]                # (G, dp, tp)
+            tw = index.doc_tw[plan.cids]
+            return score_admitted_ref(tids, tw, dseg_mod, dmask, qmaps,
+                                      plan, index.scale,
+                                      impl=resolve_score_impl(
+                                          cfg, qmaps.shape[0]))
 
-    def empty(_):
-        shape = (qmaps.shape[0], plan.cids.shape[0], index.d_pad)
-        return jnp.full(shape, NEG)
+        def empty(_):
+            shape = (qmaps.shape[0], plan.cids.shape[0], index.d_pad)
+            return jnp.full(shape, NEG)
 
-    return jax.lax.cond(plan.n_blocks > 0, dense, empty, operand=None)
+        return jax.lax.cond(plan.n_blocks > 0, dense, empty, operand=None)
+
+
+def _merge_wave(index: ClusterIndex, cids: jax.Array, scores: jax.Array,
+                theta: jax.Array, top_scores: jax.Array, top_ids: jax.Array,
+                k: int) -> tuple[jax.Array, jax.Array]:
+    """Incremental threshold-filtered merge of one wave's (n_q, G, d_pad)
+    scores into each query's running top-k: group candidates must beat
+    the query's theta; top-k of the group, then a 2k merge — never a
+    top_k over k + G*d_pad. Masked docs are NEG and theta >= NEG, so
+    the theta filter subsumes the admission mask. Returns the new
+    (top_scores, top_ids)."""
+    n_q, G, dp = scores.shape
+    kc = min(k, G * dp)
+    cand = jnp.where(scores > theta[:, None, None],
+                     scores, NEG).reshape(n_q, G * dp)
+    g_top, g_pos = jax.lax.top_k(cand, kc)
+    ids_flat = index.doc_ids[cids].reshape(-1)                # (G*dp,)
+    g_ids = jnp.where(g_top > NEG, ids_flat[g_pos], -1)
+    if kc < k:
+        g_top = jnp.pad(g_top, ((0, 0), (0, k - kc)), constant_values=NEG)
+        g_ids = jnp.pad(g_ids, ((0, 0), (0, k - kc)), constant_values=-1)
+    merged_s = jnp.concatenate([top_scores, g_top], axis=1)
+    merged_i = jnp.concatenate([top_ids, g_ids], axis=1)
+    top_scores, sel = jax.lax.top_k(merged_s, k)              # 2k -> k
+    return top_scores, jnp.take_along_axis(merged_i, sel, axis=1)
+
+
+def _visit_order(order_key: jax.Array, m_padded: int) -> tuple:
+    """(rank, shared_p, suffix) of the batch's shared walk.
+
+    rank[q, c]: position of cluster c in query q's own bound order.
+    Budgeted queries admit only clusters inside their own rank horizon
+    ``budget + n_pruned_q``, so the shared walk spends each query's
+    budget on *that query's* best clusters, and clusters the (mu, eta)
+    test prunes inside the horizon extend it — the sequential semantics
+    where skipped clusters are free (exact for G=1 in own order;
+    docs/perf.md).
+
+    shared_p: the shared visitation order (padded to whole waves) — fair
+    interleave: a cluster's priority is the best rank any query gives
+    it, so everyone's top picks land in the first groups and thetas
+    rise fast for the whole batch. Ties broken by the batch-max key
+    (normalized below 1 so it never reorders across priorities).
+
+    suffix: each query's ordering key along the shared walk, suffix-
+    maximized: once suffix[q, pos] <= theta_q / exit_div, *every*
+    cluster query q has not yet visited is provably pruned — the
+    per-query analogue of the sorted-order early exit."""
+    m = order_key.shape[1]
+    with jax.named_scope("asc.plan"):
+        rank = jnp.argsort(jnp.argsort(-order_key, axis=1), axis=1)
+        prio = rank.min(axis=0).astype(jnp.float32)              # (m,)
+        tie = order_key.max(axis=0)
+        tie = tie / (jnp.abs(tie).max() + 1.0)
+        shared = jnp.argsort(prio - tie)                         # (m,)
+        shared_p = jnp.pad(shared, (0, m_padded - m))
+        key_shared = jnp.pad(order_key[:, shared],
+                             ((0, 0), (0, m_padded - m)),
+                             constant_values=NEG)                # (n_q, mp)
+        suffix = jnp.flip(
+            jax.lax.cummax(jnp.flip(key_shared, axis=1), axis=1), axis=1)
+    return rank, shared_p, suffix
 
 
 def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
@@ -613,7 +694,6 @@ def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
     executor-replay hook.
     """
     m, G, k = index.m, cfg.group_size, cfg.k
-    dp = index.d_pad
     n_q = order_key.shape[0]
     n_groups = -(-m // G)
     m_padded = n_groups * G
@@ -621,43 +701,14 @@ def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
     n_qb = -(-n_q // block_q)
 
     budget = _resolve_budget(cfg, m, budget)
-    if mu_eta is None:
-        mu = jnp.float32(cfg.mu)
-        eta = jnp.float32(cfg.eta)
-    else:                                # per-request fidelity: (n_q,)
-        mu, eta = mu_eta[:, 0], mu_eta[:, 1]
-    exit_div = eta if cfg.method == "asc" else mu
-
-    # rank[q, c]: position of cluster c in query q's own bound order.
-    # Budgeted queries admit only clusters inside their own rank horizon
-    # `budget + n_pruned_q`, so the shared walk spends each query's budget
-    # on *that query's* best clusters, and clusters the (mu, eta) test
-    # prunes inside the horizon extend it — the sequential semantics where
-    # skipped clusters are free (exact for G=1 in own order; docs/perf.md).
-    rank = jnp.argsort(jnp.argsort(-order_key, axis=1), axis=1)  # (n_q, m)
-
-    # shared visitation order — fair interleave: a cluster's priority is
-    # the best rank any query gives it, so everyone's top picks land in
-    # the first groups and thetas rise fast for the whole batch. Ties
-    # broken by the batch-max key (normalized below 1 so it never
-    # reorders across priorities).
-    prio = rank.min(axis=0).astype(jnp.float32)                  # (m,)
-    tie = order_key.max(axis=0)
-    tie = tie / (jnp.abs(tie).max() + 1.0)
-    shared = jnp.argsort(prio - tie)                             # (m,)
-    shared_p = jnp.pad(shared, (0, m_padded - m))
-
-    # per-query ordering key along the shared walk + its suffix maximum:
-    # once suffix[q, pos] <= theta_q / exit_div, *every* cluster query q
-    # has not yet visited is provably pruned — the per-query analogue of
-    # the sorted-order early exit.
-    key_shared = jnp.pad(order_key[:, shared],
-                         ((0, 0), (0, m_padded - m)),
-                         constant_values=NEG)                    # (n_q, mp)
-    suffix = jnp.flip(
-        jax.lax.cummax(jnp.flip(key_shared, axis=1), axis=1), axis=1)
-
-    kc = min(k, G * dp)
+    with jax.named_scope("asc.plan"):
+        if mu_eta is None:
+            mu = jnp.float32(cfg.mu)
+            eta = jnp.float32(cfg.eta)
+        else:                            # per-request fidelity: (n_q,)
+            mu, eta = mu_eta[:, 0], mu_eta[:, 1]
+        exit_div = eta if cfg.method == "asc" else mu
+    rank, shared_p, suffix = _visit_order(order_key, m_padded)
 
     def _wave_plan(state_slices) -> tuple[WavePlan, jax.Array]:
         """One wave's planning from the generic per-wave slices."""
@@ -690,69 +741,55 @@ def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
 
     def cond(state):
         g, done = state[0], state[1]
-        return jnp.logical_and(g < n_groups,
-                               jnp.logical_not(jnp.all(done)))
+        with jax.named_scope("asc.merge"):
+            return jnp.logical_and(g < n_groups,
+                                   jnp.logical_not(jnp.all(done)))
 
     def body(state):
         (g, done, top_scores, top_ids,
          n_docs, n_clusters, n_segments, n_pruned,
          n_tiles_exec, n_tiles_walk, n_docs_walk, rec) = state
-        theta = top_scores[:, k - 1]                          # (n_q,)
-        pos = g * G
-        cids = jax.lax.dynamic_slice(shared_p, (pos,), (G,))  # (G,)
-        glive = (jnp.arange(G) + pos) < m                     # (G,)
-
         # ---- plan: admission + budget horizon -> compact work queues ----
-        plan, newly_pruned = _wave_plan(
-            (cids, glive, done, theta, n_clusters, n_pruned))
-        n_pruned += newly_pruned
-        admit, seg_admit = plan.admit, plan.seg_admit
+        with jax.named_scope("asc.plan"):
+            theta = top_scores[:, k - 1]                      # (n_q,)
+            pos = g * G
+            cids = jax.lax.dynamic_slice(shared_p, (pos,), (G,))  # (G,)
+            glive = (jnp.arange(G) + pos) < m                 # (G,)
+            plan, newly_pruned = _wave_plan(
+                (cids, glive, done, theta, n_clusters, n_pruned))
+            n_pruned += newly_pruned
+            admit, seg_admit = plan.admit, plan.seg_admit
 
         # ---- execute: score the compacted queues ----
         # Non-admitted and tombstoned docs come out exactly NEG, which is
         # the single source of truth for the work counter and the
         # candidate filter.
         scores = _execute_wave(index, plan, qmaps, cfg)
-        doc_admit = scores > NEG                              # (n_q,G,dp)
 
-        # incremental threshold-filtered merge: group candidates must beat
-        # the query's theta; top-k of the group then a 2k merge — never a
-        # top_k over k + G*d_pad. Masked docs are NEG and theta >= NEG,
-        # so the theta filter subsumes the admission mask.
-        cand = jnp.where(scores > theta[:, None, None],
-                         scores, NEG).reshape(n_q, G * dp)
-        g_top, g_pos = jax.lax.top_k(cand, kc)
-        ids_flat = index.doc_ids[plan.cids].reshape(-1)       # (G*dp,)
-        g_ids = jnp.where(g_top > NEG, ids_flat[g_pos], -1)
-        if kc < k:
-            g_top = jnp.pad(g_top, ((0, 0), (0, k - kc)),
-                            constant_values=NEG)
-            g_ids = jnp.pad(g_ids, ((0, 0), (0, k - kc)),
-                            constant_values=-1)
-        merged_s = jnp.concatenate([top_scores, g_top], axis=1)
-        merged_i = jnp.concatenate([top_ids, g_ids], axis=1)
-        top_scores, sel = jax.lax.top_k(merged_s, k)          # 2k -> k
-        top_ids = jnp.take_along_axis(merged_i, sel, axis=1)
+        with jax.named_scope("asc.merge"):
+            doc_admit = scores > NEG                          # (n_q,G,dp)
+            top_scores, top_ids = _merge_wave(
+                index, plan.cids, scores, theta, top_scores, top_ids, k)
 
-        n_docs += doc_admit.sum(axis=(1, 2)).astype(jnp.int32)
-        n_clusters += admit.sum(axis=1).astype(jnp.int32)
-        n_segments += seg_admit.sum(axis=(1, 2)).astype(jnp.int32)
-        n_tiles_exec += plan.n_blocks
-        n_tiles_walk += jnp.int32(G * n_qb)
-        n_docs_walk += plan.walked_docs()
+            n_docs += doc_admit.sum(axis=(1, 2)).astype(jnp.int32)
+            n_clusters += admit.sum(axis=1).astype(jnp.int32)
+            n_segments += seg_admit.sum(axis=(1, 2)).astype(jnp.int32)
+            n_tiles_exec += plan.n_blocks
+            n_tiles_walk += jnp.int32(G * n_qb)
+            n_docs_walk += plan.walked_docs()
 
-        if record_plans:
-            rec = (jax.tree_util.tree_map(
-                       lambda buf, x: buf.at[g].set(x), rec[0], plan),
-                   rec[1].at[g].set(True))
+            if record_plans:
+                rec = (jax.tree_util.tree_map(
+                           lambda buf, x: buf.at[g].set(x), rec[0], plan),
+                       rec[1].at[g].set(True))
 
-        theta_new = top_scores[:, k - 1]
-        nxt = jnp.minimum((g + 1) * G, m_padded - 1)
-        remaining = jax.lax.dynamic_slice_in_dim(
-            suffix, nxt, 1, axis=1)[:, 0]                     # (n_q,)
-        done = (done
-                | (remaining <= theta_new / exit_div)
-                | (n_clusters >= budget))
+            theta_new = top_scores[:, k - 1]
+            nxt = jnp.minimum((g + 1) * G, m_padded - 1)
+            remaining = jax.lax.dynamic_slice_in_dim(
+                suffix, nxt, 1, axis=1)[:, 0]                 # (n_q,)
+            done = (done
+                    | (remaining <= theta_new / exit_div)
+                    | (n_clusters >= budget))
         return (g + 1, done, top_scores, top_ids,
                 n_docs, n_clusters, n_segments, n_pruned,
                 n_tiles_exec, n_tiles_walk, n_docs_walk, rec)
@@ -762,16 +799,17 @@ def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
             jnp.int32(0), jnp.int32(0), jnp.int32(0), rec_init)
-    (_, _, top_scores, top_ids, n_docs, n_clusters, n_segments, _,
+    (g_end, _, top_scores, top_ids, n_docs, n_clusters, n_segments, _,
      n_tiles_exec, n_tiles_walk, n_docs_walk, rec) = (
         jax.lax.while_loop(cond, body, init))
-    top_ids = jnp.where(top_scores > NEG, top_ids, -1)
-    # batch-level tile/doc counters, replicated per query (TopK docstring)
-    tiles_exec = jnp.full((n_q,), n_tiles_exec, jnp.int32)
-    tiles_walk = jnp.full((n_q,), n_tiles_walk, jnp.int32)
-    docs_walk = jnp.full((n_q,), n_docs_walk, jnp.int32)
-    out = (top_ids, top_scores, n_docs, n_clusters, n_segments,
-           tiles_exec, tiles_walk, docs_walk)
+    with jax.named_scope("asc.merge"):
+        top_ids = jnp.where(top_scores > NEG, top_ids, -1)
+        # batch-level tile/doc/wave counters, replicated per query (TopK
+        # docstring)
+        full = lambda v: jnp.full((n_q,), v, jnp.int32)
+        out = (top_ids, top_scores, n_docs, n_clusters, n_segments,
+               full(n_tiles_exec), full(n_tiles_walk), full(n_docs_walk),
+               full(g_end))
     return out + (rec,) if record_plans else out
 
 
@@ -810,7 +848,6 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
         tile counters (TopK docstring).
     """
     m, k = index.m, cfg.k
-    dp = index.d_pad
     S, cap = index.n_super, index.super_cap
     n_seg = index.n_seg
     V = index.vocab
@@ -819,133 +856,134 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
     n_qb = -(-n_q // block_q)
 
     budget = _resolve_budget(cfg, m, budget)
-    if mu_eta is None:
-        mu = jnp.float32(cfg.mu)
-        eta = jnp.float32(cfg.eta)
-    else:                                # per-request fidelity: (n_q,)
-        mu, eta = mu_eta[:, 0], mu_eta[:, 1]
-    exit_div = eta if cfg.method == "asc" else mu
+    with jax.named_scope("asc.plan"):
+        if mu_eta is None:
+            mu = jnp.float32(cfg.mu)
+            eta = jnp.float32(cfg.eta)
+        else:                            # per-request fidelity: (n_q,)
+            mu, eta = mu_eta[:, 0], mu_eta[:, 1]
+        exit_div = eta if cfg.method == "asc" else mu
 
     # ---- level 0: coarse bounds + shared superblock order ----
-    sup = superblock_bounds(index, qmaps, use_kernel=cfg.use_kernel)
-    _, sup_max, sup_avg, sup_key = _method_stats(sup, cfg)   # (n_q, S)
-    sup_rank = jnp.argsort(jnp.argsort(-sup_key, axis=1), axis=1)
-    prio = sup_rank.min(axis=0).astype(jnp.float32)          # (S,)
-    tie = sup_key.max(axis=0)
-    tie = tie / (jnp.abs(tie).max() + 1.0)
-    shared_s = jnp.argsort(prio - tie)                       # (S,)
+    with jax.named_scope("asc.bounds"):
+        sup = superblock_bounds(index, qmaps, use_kernel=cfg.use_kernel)
+        _, sup_max, sup_avg, sup_key = _method_stats(sup, cfg)  # (n_q, S)
+    with jax.named_scope("asc.plan"):
+        sup_rank = jnp.argsort(jnp.argsort(-sup_key, axis=1), axis=1)
+        prio = sup_rank.min(axis=0).astype(jnp.float32)      # (S,)
+        tie = sup_key.max(axis=0)
+        tie = tie / (jnp.abs(tie).max() + 1.0)
+        shared_s = jnp.argsort(prio - tie)                   # (S,)
 
-    # per-query suffix max of the coarse key along the shared walk: the
-    # coarse key dominates every member's key, so once the suffix drops
-    # to theta/exit_div every unvisited *cluster* is provably pruned —
-    # the early exit is as safe as the single-level one.
-    key_shared = sup_key[:, shared_s]                        # (n_q, S)
-    suffix = jnp.flip(
-        jax.lax.cummax(jnp.flip(key_shared, axis=1), axis=1), axis=1)
+        # per-query suffix max of the coarse key along the shared walk:
+        # the coarse key dominates every member's key, so once the
+        # suffix drops to theta/exit_div every unvisited *cluster* is
+        # provably pruned — the early exit is as safe as the
+        # single-level one.
+        key_shared = sup_key[:, shared_s]                    # (n_q, S)
+        suffix = jnp.flip(
+            jax.lax.cummax(jnp.flip(key_shared, axis=1), axis=1), axis=1)
 
-    members_ord = index.super_members[shared_s]              # (S, cap)
-    mem_live = members_ord >= 0
-    # budget rank-horizon for the two-level walk: global position of each
-    # live member slot along the shared superblock walk (see docstring)
-    live_rank = (jnp.cumsum(mem_live.reshape(-1).astype(jnp.int32))
-                 - 1).reshape(S, cap)
-    sup_max_o = sup_max[:, shared_s]                         # (n_q, S)
-    sup_avg_o = sup_avg[:, shared_s]
-    sup_key_o = sup_key[:, shared_s]
+        members_ord = index.super_members[shared_s]          # (S, cap)
+        mem_live = members_ord >= 0
+        # budget rank-horizon for the two-level walk: global position of
+        # each live member slot along the shared superblock walk (see
+        # docstring)
+        live_rank = (jnp.cumsum(mem_live.reshape(-1).astype(jnp.int32))
+                     - 1).reshape(S, cap)
+        sup_max_o = sup_max[:, shared_s]                     # (n_q, S)
+        sup_avg_o = sup_avg[:, shared_s]
+        sup_key_o = sup_key[:, shared_s]
 
-    kc = min(k, cap * dp)
-    qmap_v = qmaps[:, :V]
+    with jax.named_scope("asc.bounds"):
+        qmap_v = qmaps[:, :V]
 
     def cond(state):
         w, done = state[0], state[1]
-        return jnp.logical_and(w < S, jnp.logical_not(jnp.all(done)))
+        with jax.named_scope("asc.merge"):
+            return jnp.logical_and(w < S, jnp.logical_not(jnp.all(done)))
 
     def body(state):
         (w, done, top_scores, top_ids, n_docs, n_clusters, n_segments,
          n_pruned, n_tiles_exec, n_tiles_walk, n_docs_walk,
          n_bounded, n_sup_walked) = state
-        theta = top_scores[:, k - 1]                         # (n_q,)
-        members = members_ord[w]                             # (cap,)
-        glive = members >= 0
-        cids = jnp.where(glive, members, 0)
-        rank_w = jnp.broadcast_to(live_rank[w][None], (n_q, cap))
+        with jax.named_scope("asc.plan"):
+            theta = top_scores[:, k - 1]                     # (n_q,)
+            members = members_ord[w]                         # (cap,)
+            glive = members >= 0
+            cids = jnp.where(glive, members, 0)
+            rank_w = jnp.broadcast_to(live_rank[w][None], (n_q, cap))
 
-        # level-0 admission: the identical (mu, eta) test on the coarse
-        # bounds (no budget at level 0 — the horizon gates members)
-        if cfg.method == "asc":
-            sup_pruned = ((sup_max_o[:, w] <= theta / mu)
-                          & (sup_avg_o[:, w] <= theta / eta))
-        else:
-            sup_pruned = sup_key_o[:, w] <= theta / mu
-        s_admit = ~done & ~sup_pruned                        # (n_q,)
-        walked = jnp.any(s_admit)
+            # level-0 admission: the identical (mu, eta) test on the
+            # coarse bounds (no budget at level 0 — the horizon gates
+            # members)
+            if cfg.method == "asc":
+                sup_pruned = ((sup_max_o[:, w] <= theta / mu)
+                              & (sup_avg_o[:, w] <= theta / eta))
+            else:
+                sup_pruned = sup_key_o[:, w] <= theta / mu
+            s_admit = ~done & ~sup_pruned                    # (n_q,)
+            walked = jnp.any(s_admit)
 
         def heavy(args):
             (done, top_scores, top_ids, n_docs, n_clusters, n_segments,
              n_pruned, n_tiles_exec, n_docs_walk) = args
-            # the survivors' share of the fine bound pass: one fused
-            # GEMM over this superblock's member rows only
-            sub = index.seg_max_stacked[cids]        # (cap, n_seg+1, V)
-            fused = _gemm_bounds(sub.reshape(cap * (n_seg + 1), V),
-                                 qmap_v, index.scale, cfg.use_kernel)
-            fused = fused.reshape(n_q, cap, n_seg + 1)
-            if cfg.method == "asc":
-                seg_b_w = fused[..., :n_seg]
-                max_s_w = seg_b_w.max(axis=-1)
-                avg_s_w = seg_b_w.mean(axis=-1)
-                key_w = max_s_w
-            else:
-                bs = fused[..., n_seg]
-                seg_b_w, max_s_w, avg_s_w, key_w = (bs[..., None], bs,
-                                                    bs, bs)
-            # level-0-pruned queries: force their member bounds to NEG
-            # so the shared _admission registers every member as pruned
-            # (valid — theta cleared the dominating coarse bound, which
-            # is >= 0 >= NEG — and the budget horizon bookkeeping stays
-            # identical to a wave that priced the members)
-            mq = s_admit[:, None]
-            max_s_w = jnp.where(mq, max_s_w, NEG)
-            avg_s_w = jnp.where(mq, avg_s_w, NEG)
-            key_w = jnp.where(mq, key_w, NEG)
-            seg_b_w = jnp.where(mq[:, :, None], seg_b_w, NEG)
+            with jax.named_scope("asc.bounds"):
+                # the survivors' share of the fine bound pass: one fused
+                # GEMM over this superblock's member rows only
+                sub = index.seg_max_stacked[cids]    # (cap, n_seg+1, V)
+                fused = _gemm_bounds(sub.reshape(cap * (n_seg + 1), V),
+                                     qmap_v, index.scale, cfg.use_kernel)
+                fused = fused.reshape(n_q, cap, n_seg + 1)
+                if cfg.method == "asc":
+                    seg_b_w = fused[..., :n_seg]
+                    max_s_w = seg_b_w.max(axis=-1)
+                    avg_s_w = seg_b_w.mean(axis=-1)
+                    key_w = max_s_w
+                else:
+                    bs = fused[..., n_seg]
+                    seg_b_w, max_s_w, avg_s_w, key_w = (bs[..., None], bs,
+                                                        bs, bs)
+                # level-0-pruned queries: force their member bounds to
+                # NEG so the shared _admission registers every member as
+                # pruned (valid — theta cleared the dominating coarse
+                # bound, which is >= 0 >= NEG — and the budget horizon
+                # bookkeeping stays identical to a wave that priced the
+                # members)
+                mq = s_admit[:, None]
+                max_s_w = jnp.where(mq, max_s_w, NEG)
+                avg_s_w = jnp.where(mq, avg_s_w, NEG)
+                key_w = jnp.where(mq, key_w, NEG)
+                seg_b_w = jnp.where(mq[:, :, None], seg_b_w, NEG)
 
-            plan, newly_pruned = _plan_admission(
-                cfg, cids=cids, glive=glive, done=done, theta=theta,
-                max_s_w=max_s_w, avg_s_w=avg_s_w, key_w=key_w,
-                seg_b_w=seg_b_w, rank_w=rank_w, n_clusters=n_clusters,
-                n_pruned=n_pruned, budget=budget,
-                dseg_mod_w=index.doc_seg_mod[cids],
-                dmask_w=index.doc_mask[cids], block_q=block_q,
-                block_d=block_d, soff_w=index.seg_offsets[cids],
-                su_w=index.sorted_upto[cids], mu_eta=mu_eta)
-            n_pruned += newly_pruned
+            with jax.named_scope("asc.plan"):
+                plan, newly_pruned = _plan_admission(
+                    cfg, cids=cids, glive=glive, done=done, theta=theta,
+                    max_s_w=max_s_w, avg_s_w=avg_s_w, key_w=key_w,
+                    seg_b_w=seg_b_w, rank_w=rank_w, n_clusters=n_clusters,
+                    n_pruned=n_pruned, budget=budget,
+                    dseg_mod_w=index.doc_seg_mod[cids],
+                    dmask_w=index.doc_mask[cids], block_q=block_q,
+                    block_d=block_d, soff_w=index.seg_offsets[cids],
+                    su_w=index.sorted_upto[cids], mu_eta=mu_eta)
+                n_pruned += newly_pruned
             scores = _execute_wave(index, plan, qmaps, cfg)
-            doc_admit = scores > NEG                  # (n_q, cap, dp)
 
-            cand = jnp.where(scores > theta[:, None, None], scores,
-                             NEG).reshape(n_q, cap * dp)
-            g_top, g_pos = jax.lax.top_k(cand, kc)
-            ids_flat = index.doc_ids[plan.cids].reshape(-1)
-            g_ids = jnp.where(g_top > NEG, ids_flat[g_pos], -1)
-            if kc < k:
-                g_top = jnp.pad(g_top, ((0, 0), (0, k - kc)),
-                                constant_values=NEG)
-                g_ids = jnp.pad(g_ids, ((0, 0), (0, k - kc)),
-                                constant_values=-1)
-            merged_s = jnp.concatenate([top_scores, g_top], axis=1)
-            merged_i = jnp.concatenate([top_ids, g_ids], axis=1)
-            top_scores, sel = jax.lax.top_k(merged_s, k)
-            top_ids = jnp.take_along_axis(merged_i, sel, axis=1)
-
-            n_docs += doc_admit.sum(axis=(1, 2)).astype(jnp.int32)
-            n_clusters += plan.admit.sum(axis=1).astype(jnp.int32)
-            n_segments += plan.seg_admit.sum(axis=(1, 2)).astype(
-                jnp.int32)
-            n_tiles_exec += plan.n_blocks
-            n_docs_walk += plan.walked_docs()
-            return (done, top_scores, top_ids, n_docs, n_clusters,
-                    n_segments, n_pruned, n_tiles_exec, n_docs_walk,
-                    glive.sum().astype(jnp.int32), jnp.int32(cap * n_qb))
+            with jax.named_scope("asc.merge"):
+                doc_admit = scores > NEG              # (n_q, cap, dp)
+                top_scores, top_ids = _merge_wave(
+                    index, plan.cids, scores, theta, top_scores, top_ids,
+                    k)
+                n_docs += doc_admit.sum(axis=(1, 2)).astype(jnp.int32)
+                n_clusters += plan.admit.sum(axis=1).astype(jnp.int32)
+                n_segments += plan.seg_admit.sum(axis=(1, 2)).astype(
+                    jnp.int32)
+                n_tiles_exec += plan.n_blocks
+                n_docs_walk += plan.walked_docs()
+                return (done, top_scores, top_ids, n_docs, n_clusters,
+                        n_segments, n_pruned, n_tiles_exec, n_docs_walk,
+                        glive.sum().astype(jnp.int32),
+                        jnp.int32(cap * n_qb))
 
         def skip(args):
             (done, top_scores, top_ids, n_docs, n_clusters, n_segments,
@@ -953,9 +991,10 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
             # every live member is pruned for every not-done query
             # (dominance) — pruned clusters inside the budget horizon
             # stay budget-free, exactly as _admission would count them
-            live_q = glive[None, :] & ~done[:, None]
-            gate = rank_w < (budget + n_pruned)[:, None]
-            n_pruned += (live_q & gate).sum(axis=1).astype(jnp.int32)
+            with jax.named_scope("asc.plan"):
+                live_q = glive[None, :] & ~done[:, None]
+                gate = rank_w < (budget + n_pruned)[:, None]
+                n_pruned += (live_q & gate).sum(axis=1).astype(jnp.int32)
             return (done, top_scores, top_ids, n_docs, n_clusters,
                     n_segments, n_pruned, n_tiles_exec, n_docs_walk,
                     jnp.int32(0), jnp.int32(0))
@@ -965,17 +1004,18 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
         (done, top_scores, top_ids, n_docs, n_clusters, n_segments,
          n_pruned, n_tiles_exec, n_docs_walk, bounded_w, walk_w) = (
             jax.lax.cond(walked, heavy, skip, args))
-        n_bounded += bounded_w
-        n_tiles_walk += walk_w
-        n_sup_walked += walked.astype(jnp.int32)
+        with jax.named_scope("asc.merge"):
+            n_bounded += bounded_w
+            n_tiles_walk += walk_w
+            n_sup_walked += walked.astype(jnp.int32)
 
-        theta_new = top_scores[:, k - 1]
-        nxt = jnp.minimum(w + 1, S - 1)
-        remaining = jax.lax.dynamic_slice_in_dim(
-            suffix, nxt, 1, axis=1)[:, 0]                    # (n_q,)
-        done = (done
-                | (remaining <= theta_new / exit_div)
-                | (n_clusters >= budget))
+            theta_new = top_scores[:, k - 1]
+            nxt = jnp.minimum(w + 1, S - 1)
+            remaining = jax.lax.dynamic_slice_in_dim(
+                suffix, nxt, 1, axis=1)[:, 0]                # (n_q,)
+            done = (done
+                    | (remaining <= theta_new / exit_div)
+                    | (n_clusters >= budget))
         return (w + 1, done, top_scores, top_ids, n_docs, n_clusters,
                 n_segments, n_pruned, n_tiles_exec, n_tiles_walk,
                 n_docs_walk, n_bounded, n_sup_walked)
@@ -986,16 +1026,17 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
             jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
             jnp.int32(0))
-    (_, _, top_scores, top_ids, n_docs, n_clusters, n_segments, _,
+    (w_end, _, top_scores, top_ids, n_docs, n_clusters, n_segments, _,
      n_tiles_exec, n_tiles_walk, n_docs_walk, n_bounded,
      n_sup_walked) = jax.lax.while_loop(cond, body, init)
-    top_ids = jnp.where(top_scores > NEG, top_ids, -1)
-    full = lambda v: jnp.full((n_q,), v, jnp.int32)
-    # early-exited tail superblocks were never walked: count as pruned
-    return (top_ids, top_scores, n_docs, n_clusters, n_segments,
-            full(n_tiles_exec), full(n_tiles_walk), full(n_docs_walk),
-            full(n_bounded), full(n_sup_walked),
-            full(jnp.int32(S) - n_sup_walked))
+    with jax.named_scope("asc.merge"):
+        top_ids = jnp.where(top_scores > NEG, top_ids, -1)
+        full = lambda v: jnp.full((n_q,), v, jnp.int32)
+        # early-exited tail superblocks were never walked: count as pruned
+        return (top_ids, top_scores, n_docs, n_clusters, n_segments,
+                full(n_tiles_exec), full(n_tiles_walk), full(n_docs_walk),
+                full(w_end), full(n_bounded), full(n_sup_walked),
+                full(jnp.int32(S) - n_sup_walked))
 
 
 def _method_stats(stats: dict, cfg: SearchConfig) -> tuple:
@@ -1007,21 +1048,31 @@ def _method_stats(stats: dict, cfg: SearchConfig) -> tuple:
     return bs[..., None], bs, bs, bs
 
 
+def _cluster_stats(index: ClusterIndex, queries: QueryBatch,
+                   qmaps: jax.Array, cfg: SearchConfig) -> tuple:
+    """The single-level bounds pass: (seg_b, max_s, avg_s, order_key)
+    of every cluster for every query."""
+    with jax.named_scope("asc.bounds"):
+        stats = cluster_bounds(index, queries, impl=cfg.bounds_impl,
+                               use_kernel=cfg.use_kernel, qmaps=qmaps)
+        return _method_stats(stats, cfg)
+
+
 def _retrieve_arrays(index: ClusterIndex, queries: QueryBatch,
                      cfg: SearchConfig,
                      budget: jax.Array | None = None,
                      record_plans: bool = False,
                      mu_eta: jax.Array | None = None) -> tuple:
-    """(ids, scores, n_docs, n_clusters, n_segments, n_tiles_scored,
-    n_tiles_walked, n_docs_walked), each leading n_q — plus the recorded
-    wave plans as a trailing element when ``record_plans`` (batched
-    engine only).
+    """Every TopK field in TopK's order, each leading n_q — plus the
+    recorded wave plans as a trailing element when ``record_plans``
+    (batched engine only).
 
     Shared by :func:`retrieve`, :func:`retrieve_with_plans` and the
     distributed shard-local search. The dense query maps are
     materialized exactly once and threaded through bound estimation
     *and* scoring."""
-    qmaps = queries.dense_map()                               # (n_q, V+1)
+    with jax.named_scope("asc.bounds"):
+        qmaps = queries.dense_map()                           # (n_q, V+1)
     # tiny batches can't amortize the batched planner (measured
     # regression at batch 1 — see AUTO_ENGINE_MIN_BATCH); batch size
     # is a trace-time shape, so the routing costs nothing at runtime
@@ -1038,9 +1089,8 @@ def _retrieve_arrays(index: ClusterIndex, queries: QueryBatch,
         # it prices superblocks up front and members on admission
         return _search_batch_super(index, qmaps, cfg, budget=budget,
                                    mu_eta=mu_eta)
-    stats = cluster_bounds(index, queries, impl=cfg.bounds_impl,
-                           use_kernel=cfg.use_kernel, qmaps=qmaps)
-    seg_b, max_s, avg_s, order_key = _method_stats(stats, cfg)
+    seg_b, max_s, avg_s, order_key = _cluster_stats(index, queries, qmaps,
+                                                    cfg)
     # single-level engines report the degenerate level-0 funnel: every
     # cluster bounded, every superblock walked, none pruned
     nq = queries.n_queries
@@ -1070,16 +1120,8 @@ def _retrieve_arrays(index: ClusterIndex, queries: QueryBatch,
 
 
 def _topk_of(arrays: tuple) -> TopK:
-    (ids, scores, n_docs, n_clusters, n_segments,
-     n_tiles, n_walked, n_walked_docs,
-     n_bounded, n_walked_super, n_pruned_super) = arrays
-    return TopK(doc_ids=ids, scores=scores, n_scored_docs=n_docs,
-                n_scored_clusters=n_clusters, n_scored_segments=n_segments,
-                n_scored_tiles=n_tiles, n_walked_tiles=n_walked,
-                n_walked_docs=n_walked_docs,
-                n_bounded_clusters=n_bounded,
-                n_walked_superblocks=n_walked_super,
-                n_pruned_superblocks=n_pruned_super)
+    """The TopK of a ``_retrieve_arrays`` tuple (TopK's field order)."""
+    return TopK(*arrays)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -1150,23 +1192,12 @@ def _pipeline_prologue(index: ClusterIndex, queries: QueryBatch,
     the head of :func:`_search_batch` (the bit-equality tests compare the
     two engines end to end)."""
     m, G = index.m, cfg.group_size
-    n_groups = -(-m // G)
-    m_padded = n_groups * G
-    qmaps = queries.dense_map()                               # (n_q, V+1)
-    stats = cluster_bounds(index, queries, impl=cfg.bounds_impl,
-                           use_kernel=cfg.use_kernel, qmaps=qmaps)
-    seg_b, max_s, avg_s, order_key = _method_stats(stats, cfg)
-    rank = jnp.argsort(jnp.argsort(-order_key, axis=1), axis=1)
-    prio = rank.min(axis=0).astype(jnp.float32)
-    tie = order_key.max(axis=0)
-    tie = tie / (jnp.abs(tie).max() + 1.0)
-    shared = jnp.argsort(prio - tie)
-    shared_p = jnp.pad(shared, (0, m_padded - m))
-    key_shared = jnp.pad(order_key[:, shared],
-                         ((0, 0), (0, m_padded - m)),
-                         constant_values=NEG)
-    suffix = jnp.flip(
-        jax.lax.cummax(jnp.flip(key_shared, axis=1), axis=1), axis=1)
+    m_padded = -(-m // G) * G
+    with jax.named_scope("asc.bounds"):
+        qmaps = queries.dense_map()                           # (n_q, V+1)
+    seg_b, max_s, avg_s, order_key = _cluster_stats(index, queries, qmaps,
+                                                    cfg)
+    rank, shared_p, suffix = _visit_order(order_key, m_padded)
     bud = _resolve_budget(cfg, m, budget)
     return qmaps, seg_b, max_s, avg_s, order_key, rank, shared_p, suffix, bud
 
@@ -1198,26 +1229,27 @@ def _plan_launch(index: ClusterIndex, pos, shared_p, done, top_scores,
     before any score escapes, so lag never changes results."""
     m, G = index.m, cfg.group_size
     plans = []
-    for i in range(n_waves):
-        pos_i = pos + jnp.int32(i * G)
-        cids = jax.lax.dynamic_slice(shared_p, (pos_i,), (G,))
-        glive = (jnp.arange(G) + pos_i) < m
-        lag_clusters = (lag_waves + jnp.int32(i)) * jnp.int32(G)
-        plan, _ = _plan_admission(
-            cfg, cids=cids, glive=glive, done=done,
-            theta=top_scores[:, cfg.k - 1],
-            max_s_w=max_s[:, cids], avg_s_w=avg_s[:, cids],
-            key_w=order_key[:, cids], seg_b_w=seg_b[:, cids, :],
-            rank_w=rank[:, cids], n_clusters=n_clusters,
-            n_pruned=n_pruned, budget=budget,
-            dseg_mod_w=index.doc_seg_mod[cids],
-            dmask_w=index.doc_mask[cids], block_q=block_q,
-            block_d=block_d, soff_w=index.seg_offsets[cids],
-            su_w=index.sorted_upto[cids],
-            gate_slack=lag_clusters,
-            clamp_slack=jnp.minimum(lag_clusters, jnp.int32(G)))
-        plans.append(plan)
-    n_blocks = jnp.stack([p.n_blocks for p in plans])
+    with jax.named_scope("asc.plan"):
+        for i in range(n_waves):
+            pos_i = pos + jnp.int32(i * G)
+            cids = jax.lax.dynamic_slice(shared_p, (pos_i,), (G,))
+            glive = (jnp.arange(G) + pos_i) < m
+            lag_clusters = (lag_waves + jnp.int32(i)) * jnp.int32(G)
+            plan, _ = _plan_admission(
+                cfg, cids=cids, glive=glive, done=done,
+                theta=top_scores[:, cfg.k - 1],
+                max_s_w=max_s[:, cids], avg_s_w=avg_s[:, cids],
+                key_w=order_key[:, cids], seg_b_w=seg_b[:, cids, :],
+                rank_w=rank[:, cids], n_clusters=n_clusters,
+                n_pruned=n_pruned, budget=budget,
+                dseg_mod_w=index.doc_seg_mod[cids],
+                dmask_w=index.doc_mask[cids], block_q=block_q,
+                block_d=block_d, soff_w=index.seg_offsets[cids],
+                su_w=index.sorted_upto[cids],
+                gate_slack=lag_clusters,
+                clamp_slack=jnp.minimum(lag_clusters, jnp.int32(G)))
+            plans.append(plan)
+        n_blocks = jnp.stack([p.n_blocks for p in plans])
     return tuple(plans), n_blocks
 
 
@@ -1279,13 +1311,11 @@ def _exec_fused(index: ClusterIndex, qmaps: jax.Array, plans: tuple,
     masked output.
 
     Returns (carry', all_done, per-wave exact stats arrays)."""
-    m, G, k = index.m, cfg.group_size, cfg.k
-    dp = index.d_pad
+    G, k = cfg.group_size, cfg.k
     n_q = qmaps.shape[0]
     F = len(plans)
     block_q, block_d = plans[0].block_q, plans[0].block_d
     n_qb = -(-n_q // block_q)
-    kc = min(k, G * dp)
     exit_div = jnp.float32(cfg.eta if cfg.method == "asc" else cfg.mu)
 
     (done, top_scores, top_ids, n_docs, n_clusters, n_segments, n_pruned,
@@ -1294,72 +1324,64 @@ def _exec_fused(index: ClusterIndex, qmaps: jax.Array, plans: tuple,
 
     for f in range(F):
         plan = plans[f]
-        wave_on = real[f] & ~jnp.all(done)
-        theta = top_scores[:, k - 1]
-        cids = plan.cids
-        dseg_mod = index.doc_seg_mod[cids]                   # (G, dp)
-        dmask = index.doc_mask[cids]
-        admit_ex, seg_ex, newly_pruned = _admission(
-            cfg, glive=plan.live, done=done, theta=theta,
-            max_s_w=max_s[:, cids], avg_s_w=avg_s[:, cids],
-            key_w=order_key[:, cids], seg_b_w=seg_b[:, cids, :],
-            rank_w=rank[:, cids], n_clusters=n_clusters,
-            n_pruned=n_pruned, budget=budget)
+        with jax.named_scope("asc.plan"):
+            wave_on = real[f] & ~jnp.all(done)
+            theta = top_scores[:, k - 1]
+            cids = plan.cids
+            dseg_mod = index.doc_seg_mod[cids]               # (G, dp)
+            dmask = index.doc_mask[cids]
+            admit_ex, seg_ex, newly_pruned = _admission(
+                cfg, glive=plan.live, done=done, theta=theta,
+                max_s_w=max_s[:, cids], avg_s_w=avg_s[:, cids],
+                key_w=order_key[:, cids], seg_b_w=seg_b[:, cids, :],
+                rank_w=rank[:, cids], n_clusters=n_clusters,
+                n_pruned=n_pruned, budget=budget)
 
         raw = _execute_wave(index, plan, qmaps, cfg, dseg_mod, dmask)
-        exact_plan = dataclasses.replace(plan, admit=admit_ex,
-                                         seg_admit=seg_ex)
-        mask_ex = doc_admission(exact_plan, dseg_mod, dmask)
-        scores = jnp.where(mask_ex, raw, NEG)                # (n_q,G,dp)
+        with jax.named_scope("asc.execute"):
+            exact_plan = dataclasses.replace(plan, admit=admit_ex,
+                                             seg_admit=seg_ex)
+            mask_ex = doc_admission(exact_plan, dseg_mod, dmask)
+            scores = jnp.where(mask_ex, raw, NEG)            # (n_q,G,dp)
 
-        cand = jnp.where(scores > theta[:, None, None],
-                         scores, NEG).reshape(n_q, G * dp)
-        g_top, g_pos = jax.lax.top_k(cand, kc)
-        ids_flat = index.doc_ids[cids].reshape(-1)
-        g_ids = jnp.where(g_top > NEG, ids_flat[g_pos], -1)
-        if kc < k:
-            g_top = jnp.pad(g_top, ((0, 0), (0, k - kc)),
-                            constant_values=NEG)
-            g_ids = jnp.pad(g_ids, ((0, 0), (0, k - kc)),
-                            constant_values=-1)
-        merged_s = jnp.concatenate([top_scores, g_top], axis=1)
-        merged_i = jnp.concatenate([top_ids, g_ids], axis=1)
-        new_ts, sel = jax.lax.top_k(merged_s, k)
-        new_ti = jnp.take_along_axis(merged_i, sel, axis=1)
-        top_scores = jnp.where(wave_on, new_ts, top_scores)
-        top_ids = jnp.where(wave_on, new_ti, top_ids)
+        with jax.named_scope("asc.merge"):
+            new_ts, new_ti = _merge_wave(index, cids, scores, theta,
+                                         top_scores, top_ids, k)
+            top_scores = jnp.where(wave_on, new_ts, top_scores)
+            top_ids = jnp.where(wave_on, new_ti, top_ids)
 
-        upd = lambda old, inc: old + jnp.where(wave_on, inc, 0)
-        n_docs = upd(n_docs, (scores > NEG).sum(axis=(1, 2))
-                     .astype(jnp.int32))
-        n_clusters = upd(n_clusters, admit_ex.sum(axis=1).astype(jnp.int32))
-        n_segments = upd(n_segments,
-                         seg_ex.sum(axis=(1, 2)).astype(jnp.int32))
-        n_pruned = upd(n_pruned, newly_pruned)
-        tiles_ex, blocks_ex, slots_ex = _exact_wave_stats(
-            cfg, admit_ex, seg_ex, plan.live, dseg_mod, dmask,
-            block_q, block_d)
-        n_tiles_exec = upd(n_tiles_exec, blocks_ex)
-        n_tiles_walk = upd(n_tiles_walk, jnp.int32(G * n_qb))
-        n_docs_walk = upd(n_docs_walk, slots_ex)
+            upd = lambda old, inc: old + jnp.where(wave_on, inc, 0)
+            n_docs = upd(n_docs, (scores > NEG).sum(axis=(1, 2))
+                         .astype(jnp.int32))
+            n_clusters = upd(n_clusters,
+                             admit_ex.sum(axis=1).astype(jnp.int32))
+            n_segments = upd(n_segments,
+                             seg_ex.sum(axis=(1, 2)).astype(jnp.int32))
+            n_pruned = upd(n_pruned, newly_pruned)
+            tiles_ex, blocks_ex, slots_ex = _exact_wave_stats(
+                cfg, admit_ex, seg_ex, plan.live, dseg_mod, dmask,
+                block_q, block_d)
+            n_tiles_exec = upd(n_tiles_exec, blocks_ex)
+            n_tiles_walk = upd(n_tiles_walk, jnp.int32(G * n_qb))
+            n_docs_walk = upd(n_docs_walk, slots_ex)
 
-        theta_new = top_scores[:, k - 1]
-        remaining = jax.lax.dynamic_slice_in_dim(
-            suffix, nxt[f], 1, axis=1)[:, 0]
-        done_new = (done
-                    | (remaining <= theta_new / exit_div)
-                    | (n_clusters >= budget))
-        done = jnp.where(wave_on, done_new, done)
+            theta_new = top_scores[:, k - 1]
+            remaining = jax.lax.dynamic_slice_in_dim(
+                suffix, nxt[f], 1, axis=1)[:, 0]
+            done_new = (done
+                        | (remaining <= theta_new / exit_div)
+                        | (n_clusters >= budget))
+            done = jnp.where(wave_on, done_new, done)
 
-        z = jnp.int32(0)
-        w_tiles.append(jnp.where(wave_on, tiles_ex, z))
-        w_blocks.append(jnp.where(wave_on, blocks_ex, z))
-        w_pairs.append(jnp.where(wave_on,
-                                 admit_ex.sum().astype(jnp.int32), z))
-        w_segs.append(jnp.where(wave_on,
-                                seg_ex.sum().astype(jnp.int32), z))
-        w_slots.append(jnp.where(wave_on, slots_ex, z))
-        w_on.append(wave_on)
+            z = jnp.int32(0)
+            w_tiles.append(jnp.where(wave_on, tiles_ex, z))
+            w_blocks.append(jnp.where(wave_on, blocks_ex, z))
+            w_pairs.append(jnp.where(wave_on,
+                                     admit_ex.sum().astype(jnp.int32), z))
+            w_segs.append(jnp.where(wave_on,
+                                    seg_ex.sum().astype(jnp.int32), z))
+            w_slots.append(jnp.where(wave_on, slots_ex, z))
+            w_on.append(wave_on)
 
     carry = (done, top_scores, top_ids, n_docs, n_clusters, n_segments,
              n_pruned, n_tiles_exec, n_tiles_walk, n_docs_walk)
@@ -1437,7 +1459,7 @@ def retrieve_pipelined(index: ClusterIndex, queries: QueryBatch,
     jax.block_until_ready(shared_p)
     plan_ms = (_time.perf_counter() - t0) * 1e3
     exec_ms = 0.0
-    plan_launches = exec_launches = fused_waves = 0
+    plan_launches = exec_launches = fused_waves = n_waves = 0
 
     stale = _pipeline_init_carry(n_q, k)
     inflight = None          # (carry, all_done, stats, wave_ids)
@@ -1474,7 +1496,7 @@ def retrieve_pipelined(index: ClusterIndex, queries: QueryBatch,
     def dispatch():
         """Fuse the pending plans into one executor launch."""
         nonlocal inflight, pending, pending_blocks
-        nonlocal exec_launches, fused_waves, empty_plan
+        nonlocal exec_launches, fused_waves, n_waves, empty_plan
         if not pending:
             return
         n_real = len(pending)
@@ -1499,6 +1521,7 @@ def retrieve_pipelined(index: ClusterIndex, queries: QueryBatch,
                           bud, cfg)
         inflight = (out[0], out[1], out[2], wave_ids)
         exec_launches += 1
+        n_waves += n_real
         if n_real > 1:
             fused_waves += n_real
         pending = []
@@ -1551,6 +1574,7 @@ def retrieve_pipelined(index: ClusterIndex, queries: QueryBatch,
                 n_scored_tiles=full(n_tiles_exec),
                 n_walked_tiles=full(n_tiles_walk),
                 n_walked_docs=full(n_docs_walk),
+                n_waves=full(n_waves),
                 n_bounded_clusters=full(m),
                 n_walked_superblocks=full(index.n_super),
                 n_pruned_superblocks=full(0))

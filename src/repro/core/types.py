@@ -284,7 +284,7 @@ class ClusterIndex:
     _register,
     data_fields=("doc_ids", "scores", "n_scored_docs", "n_scored_clusters",
                  "n_scored_segments", "n_scored_tiles", "n_walked_tiles",
-                 "n_walked_docs", "n_bounded_clusters",
+                 "n_walked_docs", "n_waves", "n_bounded_clusters",
                  "n_walked_superblocks", "n_pruned_superblocks"),
     meta_fields=(),
 )
@@ -314,6 +314,13 @@ class TopK:
     tests/test_rank_safety_property.py): ``n_walked_docs <=
     n_scored_tiles * d_pad`` with equality iff no doc run is skipped,
     and every admitted doc (``n_scored_docs``) lies inside a walked run.
+    n_waves: (n_q,) int32 — waves (loop iterations of the walk) run:
+    the batched engine's waves over the batch, replicated per query
+    like the tile counters (``n_waves * G * n_qb == n_walked_tiles``);
+    the two-level walk's level-0 waves (superblocks visited); the
+    per-query engine's own loop iterations; the pipelined engine's
+    dispatched waves; the distributed path's most over its cluster
+    shards; 0 for the brute-force oracle.
     n_bounded_clusters / n_walked_superblocks / n_pruned_superblocks:
     (n_q,) int32 — the level-0 funnel of the two-level walk
     (``SearchConfig.superblocks``, docs/perf.md §superblock). For the
@@ -336,6 +343,7 @@ class TopK:
     n_scored_tiles: jax.Array
     n_walked_tiles: jax.Array
     n_walked_docs: jax.Array
+    n_waves: jax.Array
     n_bounded_clusters: jax.Array
     n_walked_superblocks: jax.Array
     n_pruned_superblocks: jax.Array

@@ -56,6 +56,9 @@ AUX_COUNTERS = (
     ("superblocks_pruned", "funnel_superblocks_pruned_total",
      "superblocks the level-0 test pruned for every query (plus the "
      "early-exited tail; 0 on single-level engines)"),
+    ("waves", "funnel_waves_total",
+     "waves the walk ran (batch-level on the batched engine, summed "
+     "over queries on the per-query engine)"),
     ("clusters_bounded", "funnel_clusters_bounded_total",
      "clusters whose fine bound rows entered the bounds GEMM "
      "(members of walked superblocks; m on single-level engines)"),
@@ -88,6 +91,10 @@ def funnel_from_topk(out, *, batched: bool, n_q: int, d_pad: int,
         "tiles_walked": batch_total(out.n_walked_tiles),
         "tiles_scored": batch_total(out.n_scored_tiles),
         "doc_slots_walked": batch_total(out.n_walked_docs),
+        # waves walked: the batch's on the batched engine (one slot per
+        # query shard, like the tiles), each query's own on the per-query
+        # engine (summed)
+        "waves": batch_total(out.n_waves),
         "docs_scored": int(np.asarray(out.n_scored_docs).sum()),
         "clusters_scored": int(np.asarray(out.n_scored_clusters).sum()),
         "segments_scored": int(np.asarray(out.n_scored_segments).sum()),
@@ -129,9 +136,9 @@ class Observability:
     the plan-recording retrieval path and replays the executor to split
     planner vs executor wall time into the registry (0 disables; the
     sampled request pays the replay, unsampled requests pay nothing;
-    see docs/observability.md §planner-share). A request that is traced
-    (``trace_dir`` set and sampled) always records the split — the
-    per-wave child spans come from the same recorded plans.
+    see docs/observability.md §planner-share). Tracing does not sample
+    the split: a traced request runs no replay, and the step's own
+    phases are read from a profiler capture by their ``asc.*`` scopes.
     """
 
     def __init__(self, registry: MetricsRegistry | None = None,
@@ -162,4 +169,4 @@ class Observability:
             trace = self.tracer.request()
             want_split = bool(self.split_every
                               and rid % self.split_every == 0)
-        return rid, trace, want_split or trace.enabled
+        return rid, trace, want_split

@@ -1,18 +1,28 @@
-"""Per-request trace spans, exportable as Chrome-trace JSON (Perfetto).
+"""Layer-boundary spans: on the profiler's clock always, and per request
+as Chrome-trace JSON (Perfetto) when the request is sampled.
 
-One :class:`TraceRecorder` serves a whole process; each request opens a
-:class:`RequestTrace` whose spans nest (``plan`` / ``execute`` /
-``topk_merge`` / ``epoch_pin``, with per-wave child spans carrying
-wave-level admission counts in their ``args``). ``save`` writes the
-Chrome trace event format — ``{"traceEvents": [...]}`` with complete
-(``"ph": "X"``) events, microsecond timestamps — which loads directly in
-Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``
-(docs/observability.md §traces has the how-to).
+Every span the serving stack opens goes through :func:`host_span`, a
+``jax.profiler.TraceAnnotation``: while a profiler capture runs it lands
+in the capture beside the device's operations, on the device trace's
+clock; with no capture running it costs about a microsecond.
 
-Zero overhead when disabled: a disabled recorder hands out the single
-shared :data:`NULL_REQUEST`, whose ``span`` context manager is a no-op
-that never reads the clock and never allocates. The serving engine holds
-whatever the recorder gives it and never branches on enabledness itself.
+One :class:`TraceRecorder` serves a whole process; each sampled request
+opens a :class:`RequestTrace` whose spans (``engine.search`` with its
+``engine.prepare`` / ``engine.launch`` / ``engine.wait`` /
+``engine.account`` children; the front-end's ``frontend.dispatch`` /
+``frontend.stack`` / ``frontend.reply``) also go to a per-request file,
+in the Chrome trace event format — ``{"traceEvents": [...]}`` with
+complete (``"ph": "X"``) events, microsecond timestamps — which loads
+directly in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``
+(docs/observability.md §traces has the how-to). Every span's duration
+is measured: the served step's phases, which run inside one device
+program, are read from a profiler capture by their ``asc.*`` named
+scopes, not from these files.
+
+A disabled recorder, or a request that is not sampled, hands out the
+shared :data:`NULL_REQUEST`, whose ``span`` opens the profiler span
+alone. The serving engine holds whatever the recorder gives it and never
+branches on enabledness itself.
 
 The optional ``profile_first_n`` hook additionally wraps the first N
 requests in a ``jax.profiler`` device capture (TensorBoard-loadable),
@@ -30,33 +40,37 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
 
-class _NullSpan:
-    """Inert span: accepts the whole Span surface, does nothing."""
 
-    __slots__ = ()
+class _HostSpan(TraceAnnotation):
+    """A profiler span with the per-request span's surface, whose
+    request-side methods do nothing."""
 
     def set_args(self, **kw) -> None:
         pass
 
-    def child(self, name: str, **args) -> "_NullSpan":
-        return self
+    def child(self, name: str, **args) -> "_HostSpan":
+        return host_span(name, **args)
 
-    def __enter__(self):
-        return self
 
-    def __exit__(self, *exc):
-        return False
+def host_span(name: str, **args) -> TraceAnnotation:
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` with ``args``:
+    enter it around the work it names. It reaches a running profiler
+    capture on the device trace's clock, and costs about a microsecond
+    when none runs."""
+    return _HostSpan(name, **args)
 
 
 class _NullRequest:
-    """Inert request trace handed out by a disabled recorder."""
+    """Request trace of a request that is not sampled: profiler spans
+    only."""
 
     __slots__ = ()
     enabled = False
 
-    def span(self, name: str, **args) -> _NullSpan:
-        return NULL_SPAN
+    def span(self, name: str, **args) -> TraceAnnotation:
+        return host_span(name, **args)
 
     def instant(self, name: str, **args) -> None:
         pass
@@ -74,16 +88,16 @@ class _NullRequest:
         return False
 
 
-NULL_SPAN = _NullSpan()
 NULL_REQUEST = _NullRequest()
 
 
 class Span:
-    """One complete ("X") trace event; use as a context manager or close
-    via the owning request. Children created while open nest visually in
-    Perfetto because they share the track and sit inside [ts, ts+dur]."""
+    """One complete ("X") trace event; use as a context manager, which
+    also opens its :func:`host_span`, or close via the owning request.
+    Children created while open nest visually in Perfetto because they
+    share the track and sit inside [ts, ts+dur]."""
 
-    __slots__ = ("name", "args", "ts_us", "dur_us", "_trace")
+    __slots__ = ("name", "args", "ts_us", "dur_us", "_trace", "_host")
 
     def __init__(self, trace: "RequestTrace", name: str, args: dict):
         self._trace = trace
@@ -91,6 +105,7 @@ class Span:
         self.args = args
         self.ts_us = trace._now_us()
         self.dur_us = None
+        self._host = None
 
     def set_args(self, **kw) -> None:
         self.args.update(kw)
@@ -104,10 +119,13 @@ class Span:
             self._trace._emit(self)
 
     def __enter__(self):
+        self._host = host_span(self.name, **self.args)
+        self._host.__enter__()
         return self
 
     def __exit__(self, *exc):
         self.close()
+        self._host.__exit__(*exc)
         return False
 
 
@@ -148,19 +166,6 @@ class RequestTrace:
             "tid": self.request_id, "args": args,
         })
 
-    def synthetic_span(self, name: str, ts_us: int, dur_us: int,
-                      **args) -> None:
-        """Emit a span with caller-provided timing — used for per-wave
-        child spans whose boundaries are *reconstructed* from recorded
-        work queues rather than measured (the waves run inside one
-        fused device computation; see docs/observability.md §waves)."""
-        self.events.append({
-            "name": name, "ph": "X", "cat": "serve",
-            "ts": int(ts_us), "dur": max(int(dur_us), 0),
-            "pid": os.getpid(), "tid": self.request_id,
-            "args": args,
-        })
-
     def set_args(self, **kw) -> None:
         """Request-level metadata, attached to the enclosing request
         span at finish time."""
@@ -189,8 +194,8 @@ class TraceRecorder:
 
     ``trace_dir`` — directory for per-request ``trace_<id>.json`` files
     (created on first write). ``sample_every`` — trace every Nth request
-    (1 = all); non-sampled requests get :data:`NULL_REQUEST` and cost
-    nothing. ``profile_first_n`` — wrap the first N requests in a
+    (1 = all); non-sampled requests get :data:`NULL_REQUEST`, which opens
+    profiler spans only. ``profile_first_n`` — wrap the first N requests in a
     ``jax.profiler.trace`` capture under ``trace_dir/jax_profile``.
     """
 

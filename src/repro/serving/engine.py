@@ -19,13 +19,14 @@ Observability (repro.obs, docs/observability.md): pass an
 :class:`repro.obs.Observability` to the engine and every ``search``
 records the full pruning funnel (clusters budgeted -> tiles walked ->
 tiles scored -> doc slots walked -> docs scored) plus latency histograms
-into its metrics registry; sampled requests additionally split planner
-vs executor wall time through the :func:`planner_executor_split` seam
-and emit per-request trace spans (plan / execute / topk_merge /
-epoch_pin, per-wave children) as Perfetto-loadable Chrome-trace JSON.
-The split replay runs out-of-band: latency histograms and the adaptive
-budget only ever observe the production jitted call, sampled or not.
-With ``obs=None`` the search path is exactly the plain jitted call.
+into its metrics registry; traced requests write their spans as
+Perfetto-loadable Chrome-trace JSON, and every ``split_every``-th
+request splits planner vs executor wall time through the
+:func:`planner_executor_split` seam (a replay, out-of-band: latency
+histograms and the adaptive budget only ever observe the production
+jitted call). Every search, with or without ``obs``, opens the profiler
+spans ``engine.search`` > ``engine.prepare`` / ``engine.launch`` /
+``engine.wait`` / ``engine.account`` (repro.obs.trace.host_span).
 """
 
 from __future__ import annotations
@@ -46,6 +47,7 @@ from repro.core.types import ClusterIndex, QueryBatch, TopK
 from repro.lifecycle.snapshot import IndexSnapshot, SnapshotPublisher
 from repro.obs.funnel import Observability, funnel_from_topk, record_funnel
 from repro.obs.metrics import (LATENCY_BUCKETS_MS, MetricsRegistry)
+from repro.obs.trace import NULL_REQUEST, host_span
 
 
 class ServeStats:
@@ -385,6 +387,20 @@ class RetrievalEngine:
         jax.block_until_ready(
             self._fn(snap.index, queries, self._budget(snap), mu_eta))
 
+    def step_text(self, queries: QueryBatch, mu_eta=None) -> str:
+        """The compiled HLO text of the step that a search of a batch
+        shaped like ``queries`` runs (compiled, or loaded from the
+        compile cache, as ``warmup`` does). A TPU profiler capture names
+        the step's operations by their instructions here, and each
+        instruction's ``op_name`` holds its ``asc.*`` phase scope
+        (``repro.core.search.PHASE_SCOPES``)."""
+        if self.cfg.engine == "pipelined":
+            raise ValueError("engine='pipelined' runs a host loop of "
+                             "device launches, not one step program")
+        snap = self._resolve()
+        return self._fn.lower(snap.index, queries, self._budget(snap),
+                              mu_eta).compile().as_text()
+
     # -- the serving hot path ---------------------------------------------
     def search(self, queries: QueryBatch,
                mu_eta: jnp.ndarray | None = None,
@@ -401,61 +417,69 @@ class RetrievalEngine:
                 "serve_degraded_requests_total",
                 "requests served off the last-good epoch while the "
                 "write plane was degraded or recovering").inc()
+        n = queries.n_queries
         if obs is None:
-            return self._search_impl(queries, None, None, False,
-                                     mu_eta, budget_frac)
+            with host_span("engine.search", batch=n):
+                return self._search_impl(queries, None, NULL_REQUEST,
+                                         False, mu_eta, budget_frac)
         rid, trace, want_split = obs.next_request()
         with trace:
             with obs.tracer.maybe_profile(rid):
-                out = self._search_impl(queries, obs, trace, want_split,
-                                        mu_eta, budget_frac)
+                with trace.span("engine.search", batch=n):
+                    out = self._search_impl(queries, obs, trace,
+                                            want_split, mu_eta,
+                                            budget_frac)
         return out
 
     def _search_impl(self, queries: QueryBatch, obs, trace,
                      want_split: bool, mu_eta=None,
                      budget_frac: float | None = None) -> TopK:
-        from repro.obs.trace import NULL_REQUEST
-        if trace is None:
-            trace = NULL_REQUEST
         live = isinstance(self._source, SnapshotPublisher)
-        # pin one epoch for this request (counted as a live reader when
-        # serving a publisher, so GC metrics see in-flight queries)
-        with trace.span("epoch_pin", live=live):
+        with trace.span("engine.prepare", live=live):
+            # pin one epoch for this request (counted as a live reader
+            # when serving a publisher, so GC metrics see in-flight
+            # queries)
             snap = self._source.pin() if live else self._resolve()
-        budget = self._budget(snap)
-        if budget_frac is not None:
-            # ladder degradation: scale the *effective* budget (clamped
-            # to m first so an unbudgeted m+1 sentinel scales sanely)
-            b = min(int(budget), snap.index.m)
-            budget = jnp.int32(max(8, int(b * budget_frac)))
+            budget = self._budget(snap)
+            if budget_frac is not None:
+                # ladder degradation: scale the *effective* budget
+                # (clamped to m first so an unbudgeted m+1 sentinel
+                # scales sanely)
+                b = min(int(budget), snap.index.m)
+                budget = jnp.int32(max(8, int(b * budget_frac)))
         try:
             t0 = time.perf_counter()
-            out = jax.block_until_ready(
-                self._fn(snap.index, queries, budget, mu_eta))
+            with trace.span("engine.launch"):
+                out = self._fn(snap.index, queries, budget, mu_eta)
+            with trace.span("engine.wait"):
+                out = jax.block_until_ready(out)
             dt = time.perf_counter() - t0
             # plan recording (the split seam's replay hook) does not
             # exist on the two-level walk — sampled superblock requests
             # skip the split, keeping production latency untouched
-            if self.cfg.superblocks:
-                want_split = False
-            if want_split:
+            if want_split and not self.cfg.superblocks:
                 # out-of-band replay through the split seam for the
-                # share metrics + plan/execute spans; `dt` above stays
-                # the production jitted latency, so the latency
-                # histogram and the adaptive controller never observe
-                # the seam's warm/replay passes
-                self._search_split(snap, queries, budget, obs, trace)
+                # share metrics; `dt` above stays the production jitted
+                # latency, so the latency histogram and the adaptive
+                # controller never observe the seam's warm/replay passes
+                with trace.span("engine.split"):
+                    self._search_split(snap, queries, budget, obs, trace)
         finally:
             if live:
                 self._source.unpin(snap)
-        # final materialization + accounting: the host side of the top-k
-        # merge (device work is inside the span above)
-        with trace.span("topk_merge"):
-            per_query_ms = self.stats.record(queries.n_queries, dt)
-            self.last_epoch = snap.epoch
-            if obs is not None:
-                self._record_request(obs, trace, snap, queries, out,
-                                     budget, dt)
+        with trace.span("engine.account"):
+            self._account(obs, trace, snap, live, queries, out, budget, dt)
+        return out
+
+    def _account(self, obs, trace, snap, live: bool, queries, out,
+                 budget, dt: float) -> None:
+        """Host bookkeeping after the step: serve stats, the funnel,
+        lifecycle mirrors and the adaptive budget."""
+        per_query_ms = self.stats.record(queries.n_queries, dt)
+        self.last_epoch = snap.epoch
+        if obs is not None:
+            self._record_request(obs, trace, snap, queries, out, budget,
+                                 dt)
         if live:
             gc = self._source.gc_stats()
             self.stats.epoch_reader_counts = gc["live_readers"]
@@ -474,17 +498,14 @@ class RetrievalEngine:
                 reg.gauge("adaptive_budget_clusters",
                           "cluster budget the controller will grant "
                           "next batch").set(self.adaptive.budget())
-        return out
 
     def _search_split(self, snap, queries, budget, obs, trace) -> None:
         """Sampled request, run *after* (and outside the timing of) the
         production jitted search: replay the batch through the shared
         timing seam — a plan-recording walk + executor-only replay —
-        emit plan/execute spans (per-wave children with exact admission
-        counts, durations apportioned by each wave's walked doc slots —
-        the waves run inside one fused device computation and are not
-        individually measurable) and record the split histograms. The
-        replay's wall time is deliberately never fed to
+        record the split histograms, and on a traced request add one
+        ``wave_NNN`` instant per wave with its exact admission counts.
+        The replay's wall time is deliberately never fed to
         ``stats.record``/``adaptive.observe``: those see only the plain
         jitted path's latency."""
         if not self._split_warm:
@@ -520,27 +541,8 @@ class RetrievalEngine:
                       "waves that shared a fused executor launch in "
                       "the last sampled pipelined request").set(
                 split["fused_waves"])
-        if trace.enabled:
-            now_us = trace._now_us()
-            plan_us = int(split["planner_ms"] * 1e3)
-            exec_us = int(split["executor_ms"] * 1e3)
-            plan_args = {"planner_share": split["planner_share"]}
-            if "plan_launches" in split:
-                plan_args.update(
-                    plan_launches=split["plan_launches"],
-                    exec_launches=split["exec_launches"],
-                    fused_waves=split["fused_waves"])
-            trace.synthetic_span("plan", now_us - plan_us - exec_us,
-                                 plan_us, **plan_args)
-            total_slots = sum(w["walked_doc_slots"] for w in waves) or 1
-            trace.synthetic_span("execute", now_us - exec_us, exec_us,
-                                 n_waves=len(waves))
-            t = now_us - exec_us
-            for w in waves:
-                w_us = int(exec_us * w["walked_doc_slots"] / total_slots)
-                trace.synthetic_span(f"wave_{w['wave']:03d}", t, w_us,
-                                     **w)
-                t += w_us
+        for w in waves:
+            trace.instant(f"wave_{w['wave']:03d}", **w)
 
     def _record_request(self, obs, trace, snap, queries, out, budget,
                         dt) -> None:
@@ -656,7 +658,7 @@ def _distributed_topk(index: ClusterIndex, queries: QueryBatch,
         # engine (batched by default: shard-local waves are planned into
         # compacted work queues and executed exactly like the single-host
         # core — each local tile fetched once per batch, only if admitted)
-        (ids, scores, nd, nc, ns, nt, nw, nwd,
+        (ids, scores, nd, nc, ns, nt, nw, nwd, nwv,
          nbc, nws, nps) = _retrieve_arrays(index_local, q_local, cfg)
         # merge the per-shard top-k across the cluster axes
         for ax in caxes:
@@ -676,17 +678,20 @@ def _distributed_topk(index: ClusterIndex, queries: QueryBatch,
         # shards would overcount it shards-fold (the PR-6 shard-shape
         # lesson, applied at level 0)
         nbc = jax.lax.psum(nbc, caxes)
+        # each cluster shard walks its own waves; the batch waited for
+        # the longest walk
+        nwv = jax.lax.pmax(nwv, caxes)
         return TopK(doc_ids=ids, scores=scores, n_scored_docs=nd,
                     n_scored_clusters=nc, n_scored_segments=ns,
                     n_scored_tiles=nt, n_walked_tiles=nw,
-                    n_walked_docs=nwd, n_bounded_clusters=nbc,
+                    n_walked_docs=nwd, n_waves=nwv, n_bounded_clusters=nbc,
                     n_walked_superblocks=nws, n_pruned_superblocks=nps)
 
     out_specs = TopK(doc_ids=P(qaxis, None), scores=P(qaxis, None),
                      n_scored_docs=P(qaxis), n_scored_clusters=P(qaxis),
                      n_scored_segments=P(qaxis), n_walked_tiles=P(qaxis),
                      n_scored_tiles=P(qaxis), n_walked_docs=P(qaxis),
-                     n_bounded_clusters=P(qaxis),
+                     n_waves=P(qaxis), n_bounded_clusters=P(qaxis),
                      n_walked_superblocks=P(qaxis),
                      n_pruned_superblocks=P(qaxis))
     fn = jax.shard_map(local, mesh=mesh, in_specs=(ispecs, qspec),

@@ -65,6 +65,7 @@ from repro.core.search import SearchConfig
 from repro.core.types import PAD_TERM, QueryBatch
 from repro.lifecycle.faults import FaultInjected, fault_point
 from repro.obs.metrics import LATENCY_BUCKETS_MS
+from repro.obs.trace import NULL_REQUEST, host_span
 
 
 # ---------------------------------------------------------------------------
@@ -516,17 +517,18 @@ class StreamingFrontend:
         batch: list[_Request] = []
         done = 0
         try:
-            now = self._now()
-            with self._lock:
-                for r in self._expire_locked(now):
-                    r.complete(DeadlineExceeded(
-                        (now - r.t_submit) * 1e3, r.deadline_ms))
-                    self._m_expired.inc()
-                    done += 1
-                if self._should_dispatch_locked(now):
-                    batch = self._queue[:self.fcfg.max_batch]
-                    del self._queue[:self.fcfg.max_batch]
-                self._m_depth.set(len(self._queue))
+            with host_span("frontend.pump"):
+                now = self._now()
+                with self._lock:
+                    for r in self._expire_locked(now):
+                        r.complete(DeadlineExceeded(
+                            (now - r.t_submit) * 1e3, r.deadline_ms))
+                        self._m_expired.inc()
+                        done += 1
+                    if self._should_dispatch_locked(now):
+                        batch = self._queue[:self.fcfg.max_batch]
+                        del self._queue[:self.fcfg.max_batch]
+                    self._m_depth.set(len(self._queue))
             if batch:
                 done += self._dispatch(batch, now)
         except FaultInjected as e:
@@ -567,7 +569,6 @@ class StreamingFrontend:
                           vocab=batch[0].vocab), n
 
     def _dispatch(self, batch: list[_Request], now: float) -> int:
-        from repro.obs.trace import NULL_REQUEST
         trace = (self._obs.tracer.request() if self._obs is not None
                  else NULL_REQUEST)
         n = len(batch)
@@ -582,24 +583,28 @@ class StreamingFrontend:
                 # device would: after the batch is formed, before the
                 # engine sees it ('delay:<ms>' stalls, 'raise' unwinds)
                 fault_point("frontend.dispatch.slow_executor")
-                qb, n_real = self._stack(batch)
-                # effective fidelity is resolved NOW, not at admission:
-                # the deeper of the request's admission stamp and the
-                # controller's current level. Without this, a backlog
-                # admitted just before the ladder stepped would still be
-                # served at full fidelity — degradation would only reach
-                # requests one queue-length after the breach, which is
-                # exactly when it is too late. Stamps differ across the
-                # queue, so one batch mixes degraded and full-fidelity
-                # rows.
-                base = self.controller.level
-                steps = [self.ladder[max(r.level, base)] for r in batch]
-                levels = [max(r.level, base) for r in batch]
-                mu_eta = np.asarray(
-                    [[s.mu, s.eta] for s in steps]
-                    + [[steps[0].mu, steps[0].eta]]
-                    * (qb.n_queries - n_real), dtype=np.float32)
-                frac = min(s.budget_frac for s in steps)
+                with trace.span("frontend.stack", batch=n,
+                                bucket=_pow2_at_least(n)):
+                    qb, n_real = self._stack(batch)
+                    # effective fidelity is resolved NOW, not at
+                    # admission: the deeper of the request's admission
+                    # stamp and the controller's current level. Without
+                    # this, a backlog admitted just before the ladder
+                    # stepped would still be served at full fidelity —
+                    # degradation would only reach requests one
+                    # queue-length after the breach, which is exactly
+                    # when it is too late. Stamps differ across the
+                    # queue, so one batch mixes degraded and
+                    # full-fidelity rows.
+                    base = self.controller.level
+                    steps = [self.ladder[max(r.level, base)]
+                             for r in batch]
+                    levels = [max(r.level, base) for r in batch]
+                    mu_eta = np.asarray(
+                        [[s.mu, s.eta] for s in steps]
+                        + [[steps[0].mu, steps[0].eta]]
+                        * (qb.n_queries - n_real), dtype=np.float32)
+                    frac = min(s.budget_frac for s in steps)
                 try:
                     out = self.engine.search(
                         qb, mu_eta=mu_eta,
@@ -616,18 +621,26 @@ class StreamingFrontend:
                         labels={"kind": "exception"}).inc()
                     print(f"[frontend] dispatch failed: {e!r}")
                     return n
-        # charge service time (incl. any injected stall) to the clock —
-        # under SimClock this is the discrete-event step. A configured
-        # service_model overrides the measured wall time with a
-        # deterministic per-dispatch cost.
-        if self._service_model is not None:
-            service_ms = float(self._service_model(levels, n_real))
-        else:
-            service_ms = (time.perf_counter() - t0) * 1e3
-        self.clock.advance(service_ms / 1e3)
-        self._service_est_ms = (0.7 * self._service_est_ms
-                                + 0.3 * service_ms)
-        t_done = self._now()
+            # charge service time (incl. any injected stall) to the
+            # clock — under SimClock this is the discrete-event step. A
+            # configured service_model overrides the measured wall time
+            # with a deterministic per-dispatch cost.
+            if self._service_model is not None:
+                service_ms = float(self._service_model(levels, n_real))
+            else:
+                service_ms = (time.perf_counter() - t0) * 1e3
+            self.clock.advance(service_ms / 1e3)
+            self._service_est_ms = (0.7 * self._service_est_ms
+                                    + 0.3 * service_ms)
+            t_done = self._now()
+            with trace.span("frontend.reply", batch=n):
+                self._reply(batch, out, steps, levels, now, t_done)
+        return n
+
+    def _reply(self, batch: list[_Request], out, steps, levels,
+               now: float, t_done: float) -> None:
+        """Complete each request's future with its row of ``out`` and
+        record the queue/latency/deadline histograms."""
         ids = np.asarray(out.doc_ids)
         scores = np.asarray(out.scores)
         stats = self.engine.stats
@@ -647,10 +660,9 @@ class StreamingFrontend:
                 eta=step.eta, budget_frac=step.budget_frac,
                 level=lvl, queue_ms=queue_ms,
                 latency_ms=latency_ms, deadline_met=met))
-        self._m_batch_sz.observe(n)
+        self._m_batch_sz.observe(len(batch))
         self.controller.on_batch(queue_depth=self.queue_depth,
                                  service_est_ms=self._service_est_ms)
-        return n
 
     def warmup(self, query: QueryBatch) -> None:
         """Pay jit compilation for every power-of-two batch bucket up

@@ -17,6 +17,8 @@ Two layers of guarantees:
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -525,3 +527,51 @@ def test_queue_step_padding_maps_to_last_real_step():
                     assert not real
                 else:             # padded tile slots
                     assert (ii, jj, dd) == last_real and not real
+
+
+# ---------------------------------------------------------------------------
+# wave counter and the step's named phases
+# ---------------------------------------------------------------------------
+
+def test_wave_counter_matches_walked_tiles_and_funnel(index, queries):
+    """Each wave walks G cluster tiles for every query block, so the
+    batched engine's wave count times G x n_qb is its walked-tile count;
+    the per-query engine counts each query's own loop iterations; the
+    funnel reports the batch's waves."""
+    from repro.core.search import resolve_blocks
+    from repro.obs import funnel_from_topk
+    q, _ = queries
+    cfg = SearchConfig(k=10, mu=0.9, eta=1.0, engine="batched")
+    out = retrieve(index, q, cfg)
+    waves = np.asarray(out.n_waves)
+    assert (waves == waves[0]).all() and waves[0] > 0
+    n_qb = -(-q.n_queries // resolve_blocks(index, q.n_queries, cfg)[0])
+    np.testing.assert_array_equal(
+        waves * cfg.group_size * n_qb, np.asarray(out.n_walked_tiles))
+    f = funnel_from_topk(out, batched=True, n_q=q.n_queries,
+                         d_pad=index.d_pad, budget_clusters=index.m)
+    assert f["waves"] == int(waves[0])
+
+    ref = retrieve(index, q, dataclasses.replace(cfg, engine="per_query"))
+    # per-query: walked tiles are the visited positions of its own waves
+    G, m = cfg.group_size, index.m
+    np.testing.assert_array_equal(
+        np.minimum(np.asarray(ref.n_waves) * G, m),
+        np.asarray(ref.n_walked_tiles))
+    assert (np.asarray(brute_force_topk(index, q, 10).n_waves) == 0).all()
+
+
+@pytest.mark.parametrize("engine,superblocks", [("batched", False),
+                                                ("per_query", False),
+                                                ("batched", True)])
+def test_lowered_step_carries_every_phase_scope(index, queries, engine,
+                                                superblocks):
+    """The served step names its phases: the lowered ``retrieve`` of
+    every engine carries all four ``asc.*`` scopes in its op names."""
+    from repro.core.search import PHASE_SCOPES
+    q, _ = queries
+    cfg = SearchConfig(k=10, mu=0.9, eta=1.0, engine=engine,
+                       superblocks=superblocks)
+    text = retrieve.lower(index, q, cfg).as_text(debug_info=True)
+    for scope in PHASE_SCOPES:
+        assert f"/{scope}/" in text, scope

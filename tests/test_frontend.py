@@ -586,3 +586,53 @@ def test_serve_launcher_exits_nonzero_on_dispatch_failure(monkeypatch):
     with pytest.raises(SystemExit) as exc:
         serve.main()
     assert "failed to dispatch" in str(exc.value.code)
+
+
+# ---------------------------------------------------------------------------
+# Layer spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_layer_spans_reach_a_profiler_capture(engine, rows, tmp_path):
+    """Without any Observability, one front-end batch still opens its
+    layer spans as profiler annotations: a capture holds them on a host
+    plane, nested as the code nests them."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    fe = _frontend(engine, rows)
+    futs = [fe.submit(r) for r in rows[:4]]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fe.pump()
+    finally:
+        jax.profiler.stop_trace()
+    assert all(isinstance(f.result(), ServedResult) for f in futs)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    names = ("frontend.pump", "frontend.dispatch", "frontend.stack",
+             "frontend.reply", "engine.search", "engine.prepare",
+             "engine.launch", "engine.wait", "engine.account")
+    for name in names:
+        assert len(spans.get(name, ())) == 1, (name, sorted(spans))
+    (lo, hi, args), = spans["engine.search"]
+    assert args["batch"] == 4
+    for child in ("engine.prepare", "engine.launch", "engine.wait",
+                  "engine.account"):
+        (a, b, _), = spans[child]
+        assert lo <= a <= b <= hi, child
+    (d0, d1, _), = spans["frontend.dispatch"]
+    (s0, s1, sargs), = spans["frontend.stack"]
+    assert sargs == {"batch": 4, "bucket": 4}
+    assert d0 <= s0 <= s1 <= lo <= hi <= d1
+    (r0, _, _), = spans["frontend.reply"]
+    assert r0 >= d1

@@ -237,6 +237,7 @@ def test_funnel_from_topk_sums_one_slot_per_query_shard():
         n_walked_tiles=np.array([7, 7, 7, 7, 5, 5, 5, 5]),
         n_scored_tiles=np.array([3, 3, 3, 3, 2, 2, 2, 2]),
         n_walked_docs=np.array([30, 30, 30, 30, 20, 20, 20, 20]),
+        n_waves=np.array([6, 6, 6, 6, 4, 4, 4, 4]),
         n_scored_docs=np.arange(8),
         n_scored_clusters=np.ones(8, np.int64),
         n_scored_segments=np.ones(8, np.int64),
@@ -251,6 +252,7 @@ def test_funnel_from_topk_sums_one_slot_per_query_shard():
     assert f["tiles_walked"] == 7 + 5
     assert f["tiles_scored"] == 3 + 2
     assert f["doc_slots_walked"] == 30 + 20
+    assert f["waves"] == 6 + 4
     assert f["docs_scored"] == int(np.arange(8).sum())
     assert f["superblocks_walked"] == 4 + 3
     assert f["superblocks_pruned"] == 2 + 3
@@ -259,12 +261,14 @@ def test_funnel_from_topk_sums_one_slot_per_query_shard():
     f1 = funnel_from_topk(out, batched=True, n_q=8, d_pad=16,
                           budget_clusters=4)
     assert f1["tiles_walked"] == 7
+    assert f1["waves"] == 6
     assert f1["superblocks_walked"] == 4
     assert f1["clusters_bounded"] == 9
     # the per-query engine sums every slot regardless of sharding
     fp = funnel_from_topk(out, batched=False, n_q=8, d_pad=16,
                           budget_clusters=4, n_query_shards=2)
     assert fp["tiles_walked"] == 4 * 7 + 4 * 5
+    assert fp["waves"] == 4 * 6 + 4 * 4
     assert fp["superblocks_walked"] == 4 * 4 + 4 * 3
     assert fp["clusters_bounded"] == 4 * 9 + 4 * 6
 
@@ -365,11 +369,13 @@ print("distributed funnel consistent")
 # ---------------------------------------------------------------------------
 
 def test_engine_traces_and_split_sampling(index, queries, tmp_path):
-    """Traced requests write schema-valid Chrome traces with the span
-    hierarchy, and carry the planner/executor split (a traced request
-    always samples the split)."""
+    """Traced requests write schema-valid Chrome traces with the engine's
+    span hierarchy, every span measured; a split-sampled traced request
+    adds one instant per wave with its exact admission counts, and
+    tracing alone samples no split."""
     q, _ = queries
-    obs = Observability(trace_dir=str(tmp_path), trace_sample_every=2)
+    obs = Observability(trace_dir=str(tmp_path), trace_sample_every=2,
+                        split_every=4)
     eng = RetrievalEngine(index, SearchConfig(k=10, mu=0.9, eta=1.0,
                                               engine="batched"), obs=obs)
     eng.warmup(q)
@@ -377,25 +383,41 @@ def test_engine_traces_and_split_sampling(index, queries, tmp_path):
         eng.search(q)
     traces = sorted(glob.glob(str(tmp_path / "trace_*.json")))
     assert len(traces) == 2                  # every 2nd request sampled
-    for p in traces:
+    for i, p in enumerate(traces):
         doc = validate_chrome_trace(p)
-        names = [e["name"] for e in doc["traceEvents"]]
-        for required in ("request", "epoch_pin", "plan", "execute",
-                         "topk_merge"):
-            assert required in names, (p, names)
-        # per-wave children with exact admission counts
-        waves = [e for e in doc["traceEvents"]
-                 if e["name"].startswith("wave_")]
-        assert waves
-        for w in waves:
-            assert w["args"]["tiles_admitted"] >= 0
-            assert w["args"]["walked_doc_slots"] >= 0
-        # wave doc slots sum to the batched engine's walked-doc counter
-        ex = next(e for e in doc["traceEvents"] if e["name"] == "execute")
-        assert ex["args"]["n_waves"] == len(waves)
-    # split histograms recorded once per traced request
-    assert obs.registry.get("split_requests_total").value == 2
-    assert obs.registry.get("split_planner_ms").count == 2
+        events = doc["traceEvents"]
+        spans = {e["name"]: e for e in events if e["ph"] == "X"}
+        assert set(spans) >= {"request", "engine.search", "engine.prepare",
+                              "engine.launch", "engine.wait",
+                              "engine.account"}, (p, sorted(spans))
+        # no span reports a duration it did not measure: the replay's
+        # plan/execute/per-wave spans are gone
+        assert not {"plan", "execute", "topk_merge",
+                    "epoch_pin"} & set(spans)
+        assert not any(n.startswith("wave_") for n in spans)
+        search = spans["engine.search"]
+        assert search["args"]["batch"] == q.n_queries
+        for child in ("engine.prepare", "engine.launch", "engine.wait",
+                      "engine.account"):
+            c = spans[child]
+            assert search["ts"] <= c["ts"]
+            assert c["ts"] + c["dur"] <= search["ts"] + search["dur"] + 1
+        # per-wave instants with exact admission counts, only on the
+        # request that sampled the split (request 0; request 2 did not)
+        waves = [e for e in events if e["name"].startswith("wave_")]
+        assert all(w["ph"] == "i" for w in waves)
+        if i == 0:
+            assert waves
+            for w in waves:
+                assert w["args"]["tiles_admitted"] >= 0
+                assert w["args"]["walked_doc_slots"] >= 0
+            req = spans["request"]["args"]
+            assert len(waves) == req["waves"]
+        else:
+            assert not waves
+    # split histograms recorded once per split-sampled request only
+    assert obs.registry.get("split_requests_total").value == 1
+    assert obs.registry.get("split_planner_ms").count == 1
     share = obs.registry.get("planner_share").value
     assert 0.0 <= share <= 1.0
 
@@ -427,6 +449,21 @@ def test_split_replay_stays_out_of_latency_stats(index, queries,
     # the >=0.5 s the seam spent (warm + timed pass) never reaches the
     # batch-latency histogram the controller and p99 read
     assert eng.stats.p(100) < 250.0
+
+
+def test_engine_step_text_names_every_phase(index, queries):
+    """The compiled text of the served step carries each phase scope in
+    its instructions' op_name, which maps a device capture's operations
+    to the phases."""
+    from repro.core.search import PHASE_SCOPES
+    q, _ = queries
+    eng = RetrievalEngine(index, SearchConfig(k=10, mu=0.9, eta=1.0))
+    eng.warmup(q)
+    text = eng.step_text(q)
+    for scope in PHASE_SCOPES:
+        assert f"/{scope}/" in text, scope
+    with pytest.raises(ValueError, match="pipelined"):
+        RetrievalEngine(index, SearchConfig(engine="pipelined")).step_text(q)
 
 
 def test_next_request_rids_unique_under_threads():
