@@ -191,19 +191,36 @@ def segment_histogram(doc_seg_mod: jax.Array, doc_mask: jax.Array,
     return (oh * doc_mask[..., None].astype(jnp.int32)).sum(axis=-2)
 
 
+def seg_lookup(seg_admit: jax.Array, doc_seg_mod: jax.Array) -> jax.Array:
+    """(..., G, d_pad) bool: ``seg_admit[..., g, doc_seg_mod[g, d]]``.
+
+    seg_admit: (..., G, n_seg) bool segment admission (leading axes — a
+    query or query-block axis — broadcast against the metadata);
+    doc_seg_mod: (G, d_pad) int32 in [0, n_seg). n_seg == 1 is the
+    collapsed (anytime) table: one bit covers every doc of the tile.
+
+    Written as an OR of ``n_seg`` elementwise selects, not a gather: XLA
+    lowers ``take_along_axis`` here to a scalar-index gather over every
+    (…, G, d_pad) slot (on the TPU its (…, 1) index operand pads to 128
+    lanes), while the selects fuse into the consumer's mask."""
+    n_seg = seg_admit.shape[-1]
+    if n_seg == 1:
+        return jnp.broadcast_to(seg_admit,
+                                seg_admit.shape[:-1] + doc_seg_mod.shape[-1:])
+    acc = False
+    for j in range(n_seg):
+        acc = acc | ((doc_seg_mod == j) & seg_admit[..., j:j + 1])
+    return acc
+
+
 def _union_doc_admission(seg_admit_any: jax.Array, doc_seg_mod: jax.Array,
                          doc_mask: jax.Array) -> jax.Array:
     """(..., G, d_pad) bool: docs admitted by the given segment union.
 
     seg_admit_any: (..., G, n_seg_eff) union segment admission (leading
     axes — e.g. a query-block axis — broadcast against the (G, d_pad)
-    metadata). n_seg_eff == 1 is the collapsed (anytime) table — every
-    live doc of an admitted tile is admitted, no segment gather needed."""
-    if seg_admit_any.shape[-1] == 1:
-        return doc_mask & seg_admit_any
-    idx = jnp.broadcast_to(doc_seg_mod,
-                           seg_admit_any.shape[:-1] + doc_seg_mod.shape[-1:])
-    return doc_mask & jnp.take_along_axis(seg_admit_any, idx, axis=-1)
+    metadata)."""
+    return doc_mask & seg_lookup(seg_admit_any, doc_seg_mod)
 
 
 def _doc_runs(admit_docs: jax.Array, n_runs: int,
@@ -439,14 +456,5 @@ def doc_admission(plan: WavePlan, doc_seg_mod: jax.Array,
     of truth for masking executor output to NEG — including blocks the
     compacted grid never visited (whose kernel output is unwritten
     garbage by design)."""
-    n_seg = plan.seg_admit.shape[-1]
-    n_q = plan.admit.shape[0]
-    if n_seg == 1:
-        # collapsed (anytime) table: one admission bit per (query, tile)
-        admitted = jnp.broadcast_to(plan.seg_admit,
-                                    (n_q,) + doc_seg_mod.shape)
-    else:
-        admitted = jnp.take_along_axis(
-            plan.seg_admit, jnp.broadcast_to(
-                doc_seg_mod[None], (n_q,) + doc_seg_mod.shape), axis=2)
-    return admitted & plan.admit[:, :, None] & doc_mask[None]
+    return (seg_lookup(plan.seg_admit, doc_seg_mod)
+            & plan.admit[:, :, None] & doc_mask[None])

@@ -82,7 +82,7 @@ import numpy as np
 from repro.core.bounds import (_gemm_bounds, cluster_bounds,
                                superblock_bounds)
 from repro.core.plan import (WavePlan, _union_doc_admission, doc_admission,
-                             plan_wave, resolve_block_d)
+                             plan_wave, resolve_block_d, seg_lookup)
 from repro.core.types import ClusterIndex, QueryBatch, TopK
 from repro.kernels.score_cluster_batch.ref import (SCORE_CHUNK,
                                                    score_admitted_ref)
@@ -375,7 +375,6 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
     n_groups = -(-m // G)
     m_padded = n_groups * G
     k = cfg.k
-    n_seg_eff = seg_b.shape[1]
 
     with jax.named_scope("asc.plan"):
         order = jnp.argsort(-order_key)                      # (m,)
@@ -434,12 +433,8 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
 
         with jax.named_scope("asc.execute"):
             scores = _score_docs(index, cids, qmap, cfg)       # (G, d_pad)
-            if n_seg_eff == 1:      # collapsed (anytime) segment table
-                seg_ok = seg_admit[:, :1]                      # (G, 1)
-            else:                   # hoisted pre-modded map: no per-wave mod
-                seg_ok = jnp.take_along_axis(
-                    seg_admit, index.doc_seg_mod[cids], axis=1)
-            doc_admit = index.doc_mask[cids] & seg_ok
+            doc_admit = index.doc_mask[cids] & seg_lookup(
+                seg_admit, index.doc_seg_mod[cids])            # (G, d_pad)
             scores = jnp.where(doc_admit, scores, NEG)
 
         with jax.named_scope("asc.merge"):

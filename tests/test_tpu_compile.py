@@ -14,7 +14,9 @@ on the served path.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,13 +85,18 @@ def test_compact_front_pallas_compiles(one_chip, rows, n):
     assert "tpu_custom_call" in text
 
 
-def test_batched_retrieve_step_compiles(one_chip):
+_BATCH = 64
+
+
+@pytest.fixture(scope="module")
+def batched_step(one_chip):
     """The served step (``engine="batched"``) at SPLADE widths, m=16,
-    batch 64, with the front-end's per-request (mu, eta)."""
+    batch 64, with the front-end's per-request (mu, eta), compiled once
+    for the tests that read it."""
     from repro.core.search import SearchConfig, retrieve
     from repro.core.types import ClusterIndex, QueryBatch
     m, n_seg, dp, tp, v, b = (16, SPLADE.n_seg, SPLADE.d_pad, SPLADE.t_pad,
-                              SPLADE.vocab, 64)
+                              SPLADE.vocab, _BATCH)
 
     def s(shape, dtype):
         return _spec(one_chip, shape, dtype)
@@ -110,8 +117,36 @@ def test_batched_retrieve_step_compiles(one_chip):
                          mask=s((b, SPLADE.q_pad), jnp.bool_), vocab=v)
     cfg = SearchConfig(k=SPLADE.k, mu=SPLADE.mu, eta=SPLADE.eta,
                        engine="batched")
-    compiled, _ = _compile(
+    return cfg, _compile(
         lambda i, q, me: retrieve(i, q, cfg, mu_eta=me), index, queries,
         s((b, 2), jnp.float32))
+
+
+def test_batched_retrieve_step_compiles(batched_step):
+    _, (compiled, _) = batched_step
     # the (64, 8, 2560, 128) f32 wave gather is the largest temporary
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+# a boolean gather's output shape and slice sizes, from the step's text
+_PRED_GATHER = re.compile(
+    r"= pred\[([\d,]*)\]\S* gather\(.*slice_sizes=\{([\d,]*)\}")
+
+
+def test_batched_step_looks_up_segments_without_a_gather(batched_step):
+    """The (query, doc) admission mask and the planner's per-query-block
+    union are elementwise selects (``core.plan.seg_lookup``): no gather
+    of one admission bit per doc slot of a wave. As a gather, the
+    batch-64 mask (64 x 8 x 2,560 = 1,310,720 entries) took most of the
+    step on a v5e. The wave's own ``doc_mask`` rows are still gathered,
+    a row of 2,560 slots at a time."""
+    cfg, (_, text) = batched_step
+    wave_slots = cfg.group_size * SPLADE.d_pad
+    gathers = [(math.prod(int(d) for d in shape.split(",") if d),
+                {int(d) for d in sizes.split(",") if d}, line.strip()[:160])
+               for line in text.splitlines()
+               for shape, sizes in _PRED_GATHER.findall(line)]
+    assert gathers, "the step's boolean gathers were not found in its text"
+    for n, sizes, line in gathers:
+        assert n != _BATCH * wave_slots, line
+        assert not (n % wave_slots == 0 and sizes == {1}), line
