@@ -1,8 +1,7 @@
-"""Mean share of the exact top-10 that the served answers hold, over
-the requests that the correctness comparison scores."""
+"""Mean share of the exact top-10 found among the first 10 served
+answers (top-k's head where k < 10), over the requests that the
+correctness comparison scores."""
 
 
 def read(rec):
-    if rec["geometry"]["k"] != 10:
-        return None
     return rec["recall"]

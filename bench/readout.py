@@ -8,7 +8,9 @@ DISPATCH_SPAN = "bench.dispatch"
 def step_program(rec: dict):
     """(device seconds, launches) of the served step: the program with
     the most device time among those launched inside ``engine.search``
-    (the ``bench.dispatch`` span) in the traced window; None untraced."""
+    (the ``bench.dispatch`` span) in the traced window, a launch that
+    runs past the window's close counted by its share inside; None
+    untraced."""
     t = rec.get("trace")
     if not t:
         return None

@@ -133,6 +133,17 @@ def _pad_rows(arrays, n):
     return out
 
 
+#: recall is read at the top-min(RECALL_AT, k), whatever the k served
+RECALL_AT = 10
+
+
+def _recall(ids, ref_ids) -> float:
+    """Mean share of each row's valid ``ref_ids`` found in its ``ids``."""
+    hits = [len(set(a[a >= 0].tolist()) & set(b[b >= 0].tolist()))
+            / max(int((b >= 0).sum()), 1) for a, b in zip(ids, ref_ids)]
+    return float(np.mean(hits))
+
+
 def compare(ids, scores, ref_ids, ref_scores, pair) -> dict:
     """The numbers that decide ``correct``, over compared requests.
 
@@ -142,7 +153,10 @@ def compare(ids, scores, ref_ids, ref_scores, pair) -> dict:
     score mass of the returned top-k to that of the exact top-k (Prop 3
     of the paper bounds it below by mu). ``malformed``: answers with a
     doc id outside the corpus, a doc twice, or scores out of order.
-    ``recall``: mean share of the exact top-k that was returned."""
+    ``recall``: mean share of the exact top-min(10, k) found among the
+    first min(10, k) returned; ``recall_at_k``: mean share of the exact
+    top-k that was returned. The exact top-10 is the exact top-k's
+    head, so no second reference pass is made."""
     valid = ids >= 0
     top1 = np.maximum(np.abs(ref_scores[:, :1]), 1e-30)
     gap = np.where(valid, np.abs(scores - pair) / top1, 0.0)
@@ -153,11 +167,11 @@ def compare(ids, scores, ref_ids, ref_scores, pair) -> dict:
     mass = np.where(valid, np.nan_to_num(pair), 0.0).sum(1)
     ref_mass = np.where(ref_ids >= 0, ref_scores, 0.0).sum(1)
     ratio = np.where(ref_mass > 0, mass / np.maximum(ref_mass, 1e-30), 1.0)
-    hits = [len(set(a[a >= 0].tolist()) & set(b[b >= 0].tolist()))
-            / max(int((b >= 0).sum()), 1) for a, b in zip(ids, ref_ids)]
+    head = min(RECALL_AT, ids.shape[1])
     return {
         "score_err": float(np.nan_to_num(gap, nan=np.inf).max()),
         "prop3_ratio": float(ratio.min()),
         "malformed": int((bad_id | dup | order).sum()),
-        "recall": float(np.mean(hits)),
+        "recall": _recall(ids[:, :head], ref_ids[:, :head]),
+        "recall_at_k": _recall(ids, ref_ids),
     }

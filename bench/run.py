@@ -27,6 +27,7 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -131,18 +132,34 @@ def query_rows(q_tids, q_tw, q_mask, vocab: int) -> list:
             for i in range(q_tids.shape[0])]
 
 
-def warm(engine, row, sizes, search) -> None:
-    """Compile (or load from the cache) each batch bucket the traffic
-    forms, as ``StreamingFrontend.warmup`` does for all of them."""
+def batch_of(row, n: int, search) -> tuple:
+    """A batch of ``n`` copies of ``row`` and its (mu, eta) rows, shaped
+    as the front-end stacks one of its buckets."""
     import numpy as np
 
     from repro.core.types import QueryBatch
+    qb = QueryBatch(tids=np.repeat(row.tids, n, 0),
+                    tw=np.repeat(row.tw, n, 0),
+                    mask=np.repeat(row.mask, n, 0), vocab=row.vocab)
+    return qb, np.full((n, 2), (search.mu, search.eta), np.float32)
+
+
+def warm(engine, row, sizes, search) -> None:
+    """Compile (or load from the cache) each batch bucket the traffic
+    forms, as ``StreamingFrontend.warmup`` does for all of them."""
     for n in sizes:
-        qb = QueryBatch(tids=np.repeat(row.tids, n, 0),
-                        tw=np.repeat(row.tw, n, 0),
-                        mask=np.repeat(row.mask, n, 0), vocab=row.vocab)
-        engine.warmup(qb, mu_eta=np.full((n, 2), (search.mu, search.eta),
-                                         np.float32))
+        engine.warmup(*batch_of(row, n, search))
+
+
+def step_text_of(engine, batch) -> str | None:
+    """The compiled text of the step that ``batch`` runs (compiled before
+    the window, or loaded from the cache), which names each operation's
+    phase; None for an engine that runs no single step program."""
+    try:
+        return engine.step_text(*batch)
+    except ValueError as e:
+        log(f"no step text, so no phase is read: {e}")
+        return None
 
 
 def wrap_search(engine, calls: list, annotate: bool) -> None:
@@ -195,8 +212,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
 
-    from bench import gen, loadgen, peaks, reference, trace_reduce
-    from bench.spec import metric_reader
+    from bench import gen, loadgen, peaks, reference, scope_reduce, \
+        trace_reduce
+    from bench.spec import metric_reader, program_span_prefixes
     from repro.core.search import SearchConfig
     from repro.serving.engine import RetrievalEngine
     from repro.serving.frontend import (FrontendConfig, ServedResult,
@@ -235,6 +253,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     warm(engine, rows[0], traffic["warm_batches"], search)
     calls.clear()
     log(f"warmed batches {traffic['warm_batches']}, {since()}")
+    step_text = step_text_of(engine, batch_of(
+        rows[0], max(traffic["warm_batches"]), search)) if trace else None
 
     rng = gen.host_rng(seed, 4)
     order = rng.permutation(len(rows))
@@ -259,9 +279,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
                                                 seconds, trace)
     compiles.active = False
     compiles.close()
+    # the capture ends once the batch in flight at the window's close has
+    # replied, so that its program spans are closed; every reading is
+    # clipped to the window span all the same
+    loadgen.wait_replies(reqs, t_end + ANSWER_WAIT_S)
     if trace:
         jax.profiler.stop_trace()
-    loadgen.wait_replies(reqs, t_end + ANSWER_WAIT_S)
     fe.shutdown(drain_deadline_ms=1.0)
 
     dev = jax.devices()[0]
@@ -296,7 +319,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     pair = ref.pair_scores(q_tids[qi], q_tw[qi], q_mask[qi], ids)
     numbers = reference.compare(ids, scores, ref_ids, ref_scores, pair) \
         if served else {"score_err": float("inf"), "prop3_ratio": 0.0,
-                        "malformed": 0, "recall": 0.0}
+                        "malformed": 0, "recall": 0.0, "recall_at_k": 0.0}
     numbers["unanswered"] = unanswered
     numbers["compared"] = len(served)
     checks = checks_of(numbers, config["limits"])
@@ -312,13 +335,18 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         "calls": window_calls,
         "geometry": geometry,
         "recall": numbers["recall"],
+        "recall_at_k": numbers["recall_at_k"],
         "compiles_in_window": compiles.n,
         "peak": peaks.peak(dev.device_kind) if dev.platform == "tpu"
         else None,
         "trace": None,
+        "scopes": None,
     }
     if trace:
-        rec["trace"] = trace_reduce.reduce(trace_reduce.load(tmp))
+        captured = trace_reduce.load(tmp)
+        rec["trace"] = trace_reduce.reduce(captured)
+        rec["scopes"] = scope_reduce.reduce(
+            captured, step_text, program_span_prefixes(cell.bench_dir))
         shutil.rmtree(tmp, ignore_errors=True)
         log(f"trace read, {since()}")
     log(f"reference done, {since()}")
@@ -343,6 +371,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         result["device"]["window_s"] = t["window_s"]
         result["breakdown"] = {"device_ops": t["device_ops"],
                                "idle_gaps": t["idle_gaps"]}
+        result["idle_gaps_by_program_span"] = [
+            [name, s] for name, s in
+            rec["scopes"]["idle_by_program_span"].items()]
     result["compiles_in_window"] = compiles.n
     result["checks"] = {k: {"value": c["value"], c["bound"]: c["limit"]}
                         for k, c in checks.items()}
@@ -364,20 +395,33 @@ def open_rate(traffic: dict, config: dict, bench_dir: str) -> float:
 
 
 def call_counters(call) -> dict:
-    """Host-side numbers of one ``engine.search`` call of the window."""
+    """Host-side numbers of one ``engine.search`` call of the window.
+    ``counters`` holds every integer ``n_*`` field of the returned
+    ``TopK`` (its dataclass fields, so a counter the program adds is
+    here unnamed by the harness) as its sum, max and first value over
+    the batch's rows: per-query counters are summed, batch-level ones,
+    replicated per query, are read by their first value."""
     import numpy as np
     t0, t1, queries, out = call
     tids = np.asarray(queries.tids)[np.asarray(queries.mask)]
+    fields = {f.name: np.asarray(getattr(out, f.name))
+              for f in dataclasses.fields(out) if f.name.startswith("n_")}
+    counters = {name: {"sum": int(a.sum()), "max": int(a.max()),
+                       "first": int(a.reshape(-1)[0])}
+                for name, a in fields.items()
+                if np.issubdtype(a.dtype, np.integer)}
     return {
         "start": t0,
         "end": t1,
         "wall_s": t1 - t0,
         "rows": int(queries.n_queries),
-        "docs": int(np.asarray(out.n_scored_docs).sum()),
-        "docs_max": int(np.asarray(out.n_scored_docs).max()),
-        "clusters": int(np.asarray(out.n_scored_clusters).sum()),
-        "bounded": int(np.asarray(out.n_bounded_clusters)[0]),
+        "docs": counters["n_scored_docs"]["sum"],
+        "docs_max": counters["n_scored_docs"]["max"],
+        "clusters": counters["n_scored_clusters"]["sum"],
+        "bounded": counters["n_bounded_clusters"]["first"],
         "distinct_terms": int(np.unique(tids).size),
+        "waves": counters.get("n_waves", {}).get("first"),
+        "counters": counters,
     }
 
 

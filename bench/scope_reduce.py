@@ -18,10 +18,12 @@ a small synthetic trace, and on the compiled text of the step
   the one with the most device time among those launched inside
   ``bench.dispatch``. Its operations are those that start inside one of
   its launches in the window.
-- Program spans are host spans named ``frontend.*`` or ``engine.*``
-  (``repro.obs.trace.host_span``). Each stretch of device idle time in
-  the window belongs to the innermost program span around it: the
-  latest-starting one.
+- Program spans are the host spans (``repro.obs.trace.host_span``)
+  named under one of the program's span prefixes: ``<p>.`` for each
+  file ``program_spans/<p>.txt`` of the benchmark
+  (``spec.program_span_prefixes``), so a new prefix is a new file.
+  Each stretch of device idle time in the window belongs to the
+  innermost program span around it: the latest-starting one.
 """
 
 from __future__ import annotations
@@ -32,9 +34,9 @@ from collections import defaultdict
 
 from bench import trace_reduce
 from bench.readout import DISPATCH_SPAN, step_program
+from bench.spec import program_span_prefixes
 
 PHASE_PREFIX = "asc."
-PROGRAM_PREFIXES = ("frontend.", "engine.")
 NO_PROGRAM_SPAN = "no program span"
 CONTAINERS = ("while", "conditional", "call")
 SEARCH_SPAN, WAIT_SPAN = "engine.search", "engine.wait"
@@ -196,11 +198,12 @@ def attribute(gaps, spans, rest: str) -> dict:
     return dict(out)
 
 
-def idle_by_span(trace: dict, prefixes=PROGRAM_PREFIXES,
+def idle_by_span(trace: dict, prefixes: tuple | None = None,
                  rest: str = NO_PROGRAM_SPAN) -> dict:
     """The window's device idle seconds by innermost host span among
-    those named with ``prefixes``, averaged over the device planes."""
-    spans = _host_spans(trace, prefixes)
+    those named with ``prefixes`` (the program's, by default), averaged
+    over the device planes."""
+    spans = _host_spans(trace, prefixes or program_span_prefixes())
     per_plane = [attribute(g, spans, rest) for g in idle_gaps(trace)]
     total: dict = defaultdict(float)
     for d in per_plane:
@@ -223,15 +226,34 @@ def engine_self_ms(trace: dict) -> list:
     return out
 
 
-def reduce(trace: dict, step_text: str | None = None) -> dict:
-    """The step's phases, the engine's own time per batch and the idle
-    time by innermost program span; ``has_program_spans`` says whether
-    the program opened any span in the trace."""
+def span_totals(trace: dict, spans: list) -> dict:
+    """``{name: [count, host seconds]}`` of the ``spans`` ((start, end,
+    name)) that start in the window, each one's seconds clipped to the
+    window's close."""
+    lo, hi = trace_reduce.window(trace)
+    out: dict = {}
+    for s, e, name in spans:
+        if lo <= s < hi:
+            n, sec = out.get(name, (0, 0.0))
+            out[name] = [n + 1, sec + (min(e, hi) - s) / 1e9]
+    return dict(sorted(out.items()))
+
+
+def reduce(trace: dict, step_text: str | None = None,
+           prefixes: tuple | None = None) -> dict:
+    """The step's phases, the engine's own time per batch, the idle time
+    by innermost program span and each program span's count and time in
+    the window; ``has_program_spans`` says whether the program opened
+    any span in the trace. ``prefixes`` name the program's spans (the
+    program's, by default)."""
+    prefixes = prefixes or program_span_prefixes()
+    spans = _host_spans(trace, prefixes)
     return {
         "phases": step_phases(trace, step_text),
         "engine_self_ms": engine_self_ms(trace),
-        "idle_by_program_span": idle_by_span(trace),
-        "has_program_spans": bool(_host_spans(trace, PROGRAM_PREFIXES)),
+        "idle_by_program_span": idle_by_span(trace, prefixes),
+        "spans": span_totals(trace, spans),
+        "has_program_spans": bool(spans),
     }
 
 

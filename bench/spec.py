@@ -2,9 +2,48 @@
 
 A cell names a configuration and a traffic mix; each lives in a file of
 its own (``configs/<config>.json``, ``traffic/<traffic>.json``), and
-each metric is read by ``metrics/<name>.py``. Adding a cell, a
-configuration, a mix or a metric adds files and entries, and edits no
-file that is there.
+each metric is read by ``metrics/<name>.py``. The program's span
+prefixes are the names of ``program_spans/<prefix>.txt``. Adding a
+cell, a configuration, a mix, a metric or a span prefix adds files and
+entries, and edits no file that is there.
+
+A reader's ``read(rec)`` returns a number, or None when its record
+holds nothing to read (the harness then leaves the metric out). The
+record ``rec`` that ``run.run_cell`` builds holds:
+
+- ``setup_s``, ``t0``, ``t_end``, ``window_s``: set-up seconds, the
+  window's start and end (``time.perf_counter``) and its length.
+- ``requests``: per request of the run, ``served``, ``in_window``,
+  ``latency_ms``, ``lag_ms`` (open loop: how late it was submitted).
+- ``calls``: per ``engine.search`` call that started in the window,
+  ``start``, ``end``, ``wall_s``, ``rows``, ``served`` (requests it
+  answered), ``distinct_terms``; the pruning counters ``docs``,
+  ``docs_max``, ``clusters``, ``bounded``, ``waves`` (None where the
+  ``TopK`` has no ``n_waves``); and ``counters``: every integer
+  ``n_*`` field of the returned ``TopK`` as ``{"sum", "max",
+  "first"}`` over the batch's rows (sum or max for a per-query
+  counter, first for a batch-level one replicated per query, as
+  ``TopK``'s docstring says of each). A counter added to ``TopK`` is
+  there with no edit.
+- ``geometry``: the index's ``m``, ``d_pad``, ``t_pad``, ``n_seg``,
+  ``vocab`` and the search's ``k``.
+- ``recall``: share of the exact top-min(10, k) found among the first
+  min(10, k) answers; ``recall_at_k``: share of the exact top-k
+  returned. Both are means over the compared requests.
+- ``compiles_in_window``; ``peak``: the chip's peaks
+  (``peaks.peak``), None off a TPU.
+- ``trace`` (``--trace 1``, else None): ``trace_reduce.reduce`` of the
+  capture: ``window_s``, ``busy_s``, ``devices``, ``modules`` ([host
+  span, program, seconds, launches]), ``device_ops``, ``idle_gaps``.
+- ``scopes`` (``--trace 1``, else None): ``scope_reduce.reduce`` of
+  the capture and the step's compiled text: ``phases``
+  (``launches``, ``device_s`` by ``asc.*`` scope, ``unscoped_s``),
+  ``engine_self_ms`` (per batch), ``idle_by_program_span`` (seconds
+  by innermost program span), ``spans`` (``{name: [count, host
+  seconds]}`` of the program spans that start in the window) and
+  ``has_program_spans``. A new ``asc.*`` scope is a new key of
+  ``device_s``, and a span under a listed prefix a new key of
+  ``spans``, with no edit.
 """
 
 from __future__ import annotations
@@ -67,6 +106,15 @@ def load_cell(name: str, root: str, bench_dir: str = BENCH_DIR) -> Cell:
         per_layer=tuple(m for m in spec.get("per_layer", [])
                         if _applies(m, name)),
         bench_dir=bench_dir)
+
+
+def program_span_prefixes(bench_dir: str = BENCH_DIR) -> tuple:
+    """``<p>.`` for each ``program_spans/<p>.txt`` under ``bench_dir``:
+    the prefixes of the program's own host spans, each file saying in a
+    line which part of the program opens them."""
+    names = os.listdir(os.path.join(bench_dir, "program_spans"))
+    return tuple(sorted(f"{n[:-len('.txt')]}." for n in names
+                        if n.endswith(".txt")))
 
 
 def metric_reader(name: str, bench_dir: str = BENCH_DIR):

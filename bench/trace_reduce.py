@@ -78,8 +78,9 @@ def window(trace: dict) -> tuple[int, int]:
 
 def reduce(trace: dict) -> dict:
     """Window length, device busy time (averaged over the device planes),
-    time and count per (host span, program), the top operations and
-    the idle time by host span."""
+    time and launches per (host span, program), the top operations and
+    the idle time by host span. A launch that the window's edge cuts
+    counts by the share of it inside the window."""
     lo, hi = window(trace)
     devices = {p: lines for p, lines in trace.items()
                if p.startswith(DEVICE_PREFIX)}
@@ -98,12 +99,15 @@ def reduce(trace: dict) -> dict:
         busy_ns.append(sum(b - a for a, b in merged))
         for name, a, b in clipped:
             ops[short_op(name)] += (b - a) / 1e9
-        mods = sorted(_clip(lines.get(MODULES_LINE, []), lo, hi),
-                      key=lambda e: e[1])
-        for (name, a, b), label in zip(mods, _labels(
-                host_spans, [a for _, a, _ in mods])):
+        # a launch that runs past the window's edge counts by the share
+        # of it inside, as its time does
+        mods = sorted(((name, max(s, lo), min(s + d, hi), d)
+                       for name, s, d in lines.get(MODULES_LINE, [])
+                       if min(s + d, hi) > max(s, lo)), key=lambda e: e[1])
+        for (name, a, b, d), label in zip(mods, _labels(
+                host_spans, [a for _, a, _, _ in mods])):
             t, n = modules.get((label, name), (0.0, 0))
-            modules[(label, name)] = (t + (b - a) / 1e9, n + 1)
+            modules[(label, name)] = (t + (b - a) / 1e9, n + (b - a) / d)
         edges = [lo] + [x for ab in merged for x in ab] + [hi]
         idle = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
         for (a, b), label in zip(idle, _labels(host_spans,
@@ -115,7 +119,7 @@ def reduce(trace: dict) -> dict:
         "window_s": (hi - lo) / 1e9,
         "busy_s": sum(busy_ns) / n_dev / 1e9,
         "devices": len(devices),
-        "modules": [[label, name, t / n_dev, n // n_dev or n]
+        "modules": [[label, name, t / n_dev, n / n_dev]
                     for (label, name), (t, n) in sorted(modules.items())],
         "device_ops": _top({k: v / n_dev for k, v in ops.items()}),
         "idle_gaps": _top({k: v / n_dev for k, v in gaps.items()}),

@@ -1,7 +1,8 @@
 """A tiny copy of the benchmark's cells for CPU tests: the real
 configuration and traffic files shrunk to a toy size, laid out as the
 harness finds them (``BENCHMARK.json`` plus ``configs/``, ``traffic/``,
-``capacity/`` and ``metrics/`` under one directory)."""
+``capacity/``, ``metrics/`` and ``program_spans/`` under one
+directory)."""
 
 from __future__ import annotations
 
@@ -31,8 +32,9 @@ def make_tiny(root: str) -> str:
     under ``root``; returns ``root``."""
     for d in ("configs", "traffic", "capacity"):
         os.makedirs(os.path.join(root, d), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    os.path.join(root, "metrics"), dirs_exist_ok=True)
+    for d in ("metrics", "program_spans"):
+        shutil.copytree(os.path.join(BENCH, d), os.path.join(root, d),
+                        dirs_exist_ok=True)
     c = _load("configs", "msmarco-splade-shard8.json")
     c["name"] = "tiny"
     c["corpus"].update(TINY_CORPUS)
@@ -71,7 +73,14 @@ PER_LAYER = [(n, u, CLOSED, layer, "qps") for n, u, layer in (
     ("step_roofline", "%", "served step"),
     ("docs_admitted_per_query", "docs", "pruning"),
     ("clusters_scored_share", "ratio", "pruning"),
-    ("device_idle_share", "ratio", "device"))]
+    ("device_idle_share", "ratio", "device"),
+    ("step_bounds_ms", "ms", "served step: bounds"),
+    ("step_plan_ms", "ms", "planner"),
+    ("step_execute_ms", "ms", "executor"),
+    ("step_merge_ms", "ms", "top-k merge"),
+    ("waves_per_batch", "waves", "pruning: wave walk"),
+    ("engine_self_ms_per_batch", "ms", "engine dispatch"),
+    ("frontend_idle_ms_per_batch", "ms", "front-end"))]
 
 
 def _metric(name, unit, workloads, bound):

@@ -150,6 +150,13 @@ def test_new_files_are_picked_up_with_no_edit(tmp_path):
         json.dump(tr, f)
     with open(os.path.join(root, "metrics", "answered.py"), "w") as f:
         f.write("def read(rec):\n    return len(rec['requests'])\n")
+    # readers of a new TopK counter and of a program span, by name
+    with open(os.path.join(root, "metrics", "merged_rows.py"), "w") as f:
+        f.write("def read(rec):\n    return sum(c['counters']['n_merged']"
+                "['sum'] for c in rec['calls'])\n")
+    with open(os.path.join(root, "metrics", "routes.py"), "w") as f:
+        f.write("def read(rec):\n    return rec['scopes']['spans']"
+                "['router.route'][0]\n")
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         b = json.load(f)
     b["configs"].append({"name": "tiny2", "source": "t", "reduced": [],
@@ -166,6 +173,11 @@ def test_new_files_are_picked_up_with_no_edit(tmp_path):
     assert cell.config["index"]["m"] == 6 and cell.traffic["clients"] == 4
     assert "answered" in {m["name"] for m in cell.per_layer}
     assert spec.metric_reader("answered", root)({"requests": [1, 2]}) == 2
+    calls = [{"counters": {"n_merged": {"sum": n, "max": n, "first": n}}}
+             for n in (3, 4)]
+    assert spec.metric_reader("merged_rows", root)({"calls": calls}) == 7
+    scopes = {"spans": {"router.route": [5, 0.25]}}
+    assert spec.metric_reader("routes", root)({"scopes": scopes}) == 5
     assert tiny_cell(root).traffic["clients"] == 16      # others unchanged
 
 

@@ -109,6 +109,10 @@ def test_main_traced_run_in_a_fresh_checkout(root, monkeypatch, capsys):
     assert "breakdown" in result and "window_s" in result["device"]
     assert {"docs_admitted_per_query", "clusters_scored_share"} <= set(
         result["metrics"])
+    # the wave counter reaches its reader; the idle time by program span
+    # rides on the line (empty off the chip: the capture has no device)
+    assert result["metrics"]["waves_per_batch"]["value"] >= 1
+    assert isinstance(result["idle_gaps_by_program_span"], list)
     assert err.strip().splitlines()[-1].startswith("[bench] check ")
 
 

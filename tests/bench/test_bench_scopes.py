@@ -5,11 +5,15 @@ form with the step's compiled text beside it."""
 
 from __future__ import annotations
 
+import dataclasses
+
+import numpy as np
 import pytest
 
-import _bench_tiny  # noqa: F401
-from bench import scope_reduce, trace_reduce
+from _bench_tiny import make_tiny
+from bench import run, scope_reduce, spec, trace_reduce
 from bench.spec import metric_reader
+from repro.core.types import QueryBatch, TopK
 
 # the step's compiled text, as RetrievalEngine.step_text gives it: the
 # phases ride in the op_name metadata; the compiler's copy.7 has none
@@ -208,3 +212,111 @@ def test_a_program_without_spans_or_scopes_reads_nothing():
             assert metric_reader(n)(rec) is None, n
         assert rec["scopes"]["idle_by_program_span"] == pytest.approx(
             {"no program span": 2510e-9})
+
+
+def test_program_span_prefixes_are_the_files_of_program_spans():
+    assert spec.program_span_prefixes() == ("engine.", "frontend.")
+
+
+def test_spans_in_the_window_by_name():
+    spans = scope_reduce.reduce(TRACE, STEP_TEXT)["spans"]
+    assert set(spans) == {n for n, _, _ in TRACE["/host:CPU"]["python"]
+                          if not n.startswith("bench.")}
+    assert spans["frontend.dispatch"] == [2, pytest.approx(8000e-9)]
+    # the second dispatch runs past the window's close at 11000
+    assert spans["frontend.reply"] == [2, pytest.approx(380e-9)]
+    assert spans["engine.prepare"] == [1, pytest.approx(20e-9)]
+
+
+def test_a_span_under_a_new_prefix_reaches_the_readers(tmp_path):
+    """A prefix declared by one new file of ``program_spans/``: its spans
+    are in ``spans`` and take their idle time in
+    ``idle_by_program_span``, with no other edit."""
+    root = make_tiny(str(tmp_path))
+    (tmp_path / "program_spans" / "router.txt").write_text(
+        "a router in front of the engines: router.route\n")
+    prefixes = spec.program_span_prefixes(root)
+    assert prefixes == ("engine.", "frontend.", "router.")
+    trace = {**TRACE, "/host:CPU": {"python": TRACE["/host:CPU"]["python"]
+                                    + [("router.route", 9500, 300)]}}
+    scopes = scope_reduce.reduce(trace, STEP_TEXT, prefixes)
+    assert scopes["spans"]["router.route"] == [1, pytest.approx(300e-9)]
+    # the idle 9500-9700 before the other program: the route span starts
+    # inside the last reply (9420-9600) and is the innermost there
+    assert scopes["idle_by_program_span"]["router.route"] == pytest.approx(
+        200e-9)
+    assert scopes["idle_by_program_span"]["frontend.reply"] == pytest.approx(
+        280e-9)
+    without = scope_reduce.reduce(trace, STEP_TEXT)
+    assert "router.route" not in without["spans"]
+    assert "router.route" not in without["idle_by_program_span"]
+
+
+def test_the_seven_readers_read_a_record_built_as_the_harness_builds_it():
+    """``calls`` from ``run.call_counters`` on returned ``TopK``s, and
+    ``trace`` / ``scopes`` as ``run.run_cell`` reduces a capture: each
+    of the seven readers gives a number."""
+    def out(waves):
+        n = np.full(64, 1, np.int32)
+        return TopK(doc_ids=np.zeros((64, 10), np.int32),
+                    scores=np.zeros((64, 10), np.float32),
+                    **{f.name: n * (waves if f.name == "n_waves" else 1)
+                       for f in dataclasses.fields(TopK)
+                       if f.name.startswith("n_")})
+    q = QueryBatch(tids=np.zeros((64, 4), np.int32),
+                   tw=np.ones((64, 4), np.float32),
+                   mask=np.ones((64, 4), bool), vocab=8)
+    rec = {"calls": [run.call_counters((1.0, 2.0, q, out(w)))
+                     for w in (6, 8)],
+           "trace": trace_reduce.reduce(TRACE),
+           "scopes": scope_reduce.reduce(TRACE, STEP_TEXT,
+                                         spec.program_span_prefixes())}
+    read = {n: metric_reader(n)(rec) for n in NEW_METRICS}
+    assert all(v is not None for v in read.values()), read
+    assert read["waves_per_batch"] == 7.0
+    assert read == pytest.approx(
+        {n: metric_reader(n)(_rec(TRACE)) for n in NEW_METRICS})
+
+
+def test_a_new_counter_span_and_scope_reach_readers_added_as_files(tmp_path):
+    """A configuration's new ``TopK`` counter, its span under a new
+    prefix and its new ``asc.*`` scope each reach a reader that is added
+    as a new file; the harness's code is not edited."""
+    root = make_tiny(str(tmp_path))
+    (tmp_path / "program_spans" / "router.txt").write_text("router.route\n")
+    readers = {
+        "merge_rows_per_batch": "return sum(c['counters']['n_merge_rows']"
+                                "['first'] for c in rec['calls'])",
+        "routes_per_window": "return rec['scopes']['spans']"
+                             "['router.route'][0]",
+        "step_rerank_ms": "from bench.scope_reduce import phase_ms\n"
+                          "    return phase_ms(rec, 'asc.rerank')",
+    }
+    for name, body in readers.items():
+        (tmp_path / "metrics" / f"{name}.py").write_text(
+            f"def read(rec):\n    {body}\n")
+
+    @dataclasses.dataclass(frozen=True)
+    class Wider(TopK):
+        n_merge_rows: np.ndarray = None
+
+    n = np.ones(2, np.int32)
+    out = Wider(doc_ids=np.zeros((2, 10), np.int32),
+                scores=np.zeros((2, 10), np.float32),
+                n_merge_rows=np.array([1000, 1000], np.int32),
+                **{f.name: n for f in dataclasses.fields(TopK)
+                   if f.name.startswith("n_")})
+    q = QueryBatch(tids=np.zeros((2, 4), np.int32),
+                   tw=np.ones((2, 4), np.float32),
+                   mask=np.ones((2, 4), bool), vocab=8)
+    trace = {**TRACE, "/host:CPU": {"python": TRACE["/host:CPU"]["python"]
+                                    + [("router.route", 9500, 300)]}}
+    rec = {"calls": [run.call_counters((1.0, 2.0, q, out))] * 2,
+           "trace": trace_reduce.reduce(trace),
+           "scopes": scope_reduce.reduce(
+               trace, STEP_TEXT.replace("asc.merge", "asc.rerank"),
+               spec.program_span_prefixes(root))}
+    read = {n: metric_reader(n, root)(rec) for n in readers}
+    assert read["merge_rows_per_batch"] == 2000
+    assert read["routes_per_window"] == 1
+    assert read["step_rerank_ms"] == pytest.approx(200e-6)

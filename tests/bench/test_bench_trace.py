@@ -109,3 +109,22 @@ def test_peak_table():
     assert "TPU v5e" in v5e["source"]
     with pytest.raises(KeyError, match="no peaks"):
         peaks.peak("TPU v9 imaginary")
+
+
+def test_a_launch_cut_by_the_window_counts_by_its_share_inside():
+    """The capture runs on until the last batch has replied: the step
+    launched before the window's close and running past it counts by
+    the part inside, so its time per launch stays the launches'."""
+    host = TRACE["/host:CPU"]["python"] + [("bench.dispatch", 10000, 2000)]
+    dev = TRACE["/device:TPU:0"]
+    trace = {"/host:CPU": {"python": host}, "/device:TPU:0": {
+        "XLA Ops": dev["XLA Ops"] + [("fusion.1", 10100, 2150)],
+        "XLA Modules": dev["XLA Modules"] + [("jit__lambda", 10100, 2150)]}}
+    r = trace_reduce.reduce(trace)
+    mods = {(lbl, name): (t, n) for lbl, name, t, n in r["modules"]}
+    # 900 of its 2150 ns lie inside the window, which ends at 11000
+    assert mods[("bench.dispatch", "jit__lambda")] == (
+        pytest.approx(5.2e-6), pytest.approx(2 + 900 / 2150))
+    assert metric_reader("step_device_ms")(_rec(r)) == pytest.approx(
+        5.2e-3 / (2 + 900 / 2150))
+    assert r["window_s"] - r["busy_s"] == pytest.approx(10e-6 - 5.2e-6)
