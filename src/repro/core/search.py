@@ -324,9 +324,13 @@ def brute_force_topk(index: ClusterIndex, queries: QueryBatch,
                                 index.scale)                 # (m, d_pad)
         scores = jnp.where(index.doc_mask, scores, NEG)
         flat = scores.reshape(-1)
+        ids = index.doc_ids.reshape(-1)
+        if k > flat.shape[0]:          # more ranks than slots: -1 pads
+            flat = jnp.pad(flat, (0, k - flat.shape[0]),
+                           constant_values=NEG)
+            ids = jnp.pad(ids, (0, k - ids.shape[0]), constant_values=-1)
         top, pos = jax.lax.top_k(flat, k)
-        ids = index.doc_ids.reshape(-1)[pos]
-        return top, jnp.where(top > NEG, ids, -1)
+        return top, jnp.where(top > NEG, ids[pos], -1)
 
     scores, ids = jax.vmap(one)(qmaps)
     n_docs = index.doc_mask.sum().astype(jnp.int32)
@@ -343,6 +347,7 @@ def brute_force_topk(index: ClusterIndex, queries: QueryBatch,
         n_bounded_clusters=m_full,
         n_walked_superblocks=jnp.full((nq,), index.n_super, jnp.int32),
         n_pruned_superblocks=jnp.zeros((nq,), jnp.int32),
+        n_merge_candidates=jnp.full((nq,), n_docs),
     )
 
 
@@ -402,7 +407,8 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
             return jnp.logical_and(g < n_groups, jnp.logical_not(done))
 
     def body(state):
-        g, done, top_scores, top_ids, n_docs, n_clusters, n_segments = state
+        (g, done, top_scores, top_ids, n_docs, n_clusters, n_segments,
+         n_merge) = state
         with jax.named_scope("asc.plan"):
             theta = top_scores[k - 1]
             pos = g * G
@@ -438,6 +444,7 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
             scores = jnp.where(doc_admit, scores, NEG)
 
         with jax.named_scope("asc.merge"):
+            n_merge += (scores > theta).sum().astype(jnp.int32)
             cand_scores = jnp.concatenate([top_scores, scores.reshape(-1)])
             cand_ids = jnp.concatenate([top_ids,
                                         index.doc_ids[cids].reshape(-1)])
@@ -454,13 +461,13 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
             # budget exhaustion also terminates
             done = jnp.logical_or(done, n_clusters >= budget)
         return (g + 1, done, top_scores, top_ids,
-                n_docs, n_clusters, n_segments)
+                n_docs, n_clusters, n_segments, n_merge)
 
     init = (jnp.int32(0), jnp.array(False),
             jnp.full((k,), NEG), jnp.full((k,), -1, jnp.int32),
-            jnp.int32(0), jnp.int32(0), jnp.int32(0))
-    (g_end, _, top_scores, top_ids, n_docs, n_clusters, n_segments) = (
-        jax.lax.while_loop(cond, body, init))
+            jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0))
+    (g_end, _, top_scores, top_ids, n_docs, n_clusters, n_segments,
+     n_merge) = jax.lax.while_loop(cond, body, init)
     with jax.named_scope("asc.merge"):
         top_ids = jnp.where(top_scores > NEG, top_ids, -1)
         # tile counters in per-query terms (see TopK docstring): every
@@ -470,7 +477,7 @@ def _search_one_query(index: ClusterIndex, qmap: jax.Array,
         # slots per scored tile
         return (top_ids, top_scores, n_docs, n_clusters, n_segments,
                 n_clusters, jnp.minimum(g_end * G, jnp.int32(m)),
-                n_clusters * jnp.int32(index.d_pad), g_end)
+                n_clusters * jnp.int32(index.d_pad), g_end, n_merge)
 
 
 def _admission(cfg: SearchConfig, *, glive, done, theta, max_s_w, avg_s_w,
@@ -611,17 +618,19 @@ def _execute_wave(index: ClusterIndex, plan: WavePlan, qmaps: jax.Array,
 
 def _merge_wave(index: ClusterIndex, cids: jax.Array, scores: jax.Array,
                 theta: jax.Array, top_scores: jax.Array, top_ids: jax.Array,
-                k: int) -> tuple[jax.Array, jax.Array]:
+                k: int) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Incremental threshold-filtered merge of one wave's (n_q, G, d_pad)
     scores into each query's running top-k: group candidates must beat
     the query's theta; top-k of the group, then a 2k merge — never a
     top_k over k + G*d_pad. Masked docs are NEG and theta >= NEG, so
     the theta filter subsumes the admission mask. Returns the new
-    (top_scores, top_ids)."""
+    (top_scores, top_ids) and each query's count of candidates that
+    beat theta (``TopK.n_merge_candidates``'s share of the wave)."""
     n_q, G, dp = scores.shape
     kc = min(k, G * dp)
-    cand = jnp.where(scores > theta[:, None, None],
-                     scores, NEG).reshape(n_q, G * dp)
+    beat = scores > theta[:, None, None]
+    n_cand = beat.sum(axis=(1, 2)).astype(jnp.int32)
+    cand = jnp.where(beat, scores, NEG).reshape(n_q, G * dp)
     g_top, g_pos = jax.lax.top_k(cand, kc)
     ids_flat = index.doc_ids[cids].reshape(-1)                # (G*dp,)
     g_ids = jnp.where(g_top > NEG, ids_flat[g_pos], -1)
@@ -631,7 +640,7 @@ def _merge_wave(index: ClusterIndex, cids: jax.Array, scores: jax.Array,
     merged_s = jnp.concatenate([top_scores, g_top], axis=1)
     merged_i = jnp.concatenate([top_ids, g_ids], axis=1)
     top_scores, sel = jax.lax.top_k(merged_s, k)              # 2k -> k
-    return top_scores, jnp.take_along_axis(merged_i, sel, axis=1)
+    return top_scores, jnp.take_along_axis(merged_i, sel, axis=1), n_cand
 
 
 def _visit_order(order_key: jax.Array, m_padded: int) -> tuple:
@@ -743,7 +752,7 @@ def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
     def body(state):
         (g, done, top_scores, top_ids,
          n_docs, n_clusters, n_segments, n_pruned,
-         n_tiles_exec, n_tiles_walk, n_docs_walk, rec) = state
+         n_tiles_exec, n_tiles_walk, n_docs_walk, n_merge, rec) = state
         # ---- plan: admission + budget horizon -> compact work queues ----
         with jax.named_scope("asc.plan"):
             theta = top_scores[:, k - 1]                      # (n_q,)
@@ -763,8 +772,9 @@ def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
 
         with jax.named_scope("asc.merge"):
             doc_admit = scores > NEG                          # (n_q,G,dp)
-            top_scores, top_ids = _merge_wave(
+            top_scores, top_ids, n_cand = _merge_wave(
                 index, plan.cids, scores, theta, top_scores, top_ids, k)
+            n_merge += n_cand
 
             n_docs += doc_admit.sum(axis=(1, 2)).astype(jnp.int32)
             n_clusters += admit.sum(axis=1).astype(jnp.int32)
@@ -787,15 +797,16 @@ def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
                     | (n_clusters >= budget))
         return (g + 1, done, top_scores, top_ids,
                 n_docs, n_clusters, n_segments, n_pruned,
-                n_tiles_exec, n_tiles_walk, n_docs_walk, rec)
+                n_tiles_exec, n_tiles_walk, n_docs_walk, n_merge, rec)
 
     init = (jnp.int32(0), jnp.zeros((n_q,), bool),
             jnp.full((n_q, k), NEG), jnp.full((n_q, k), -1, jnp.int32),
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
-            jnp.int32(0), jnp.int32(0), jnp.int32(0), rec_init)
+            jnp.int32(0), jnp.int32(0), jnp.int32(0),
+            jnp.zeros((n_q,), jnp.int32), rec_init)
     (g_end, _, top_scores, top_ids, n_docs, n_clusters, n_segments, _,
-     n_tiles_exec, n_tiles_walk, n_docs_walk, rec) = (
+     n_tiles_exec, n_tiles_walk, n_docs_walk, n_merge, rec) = (
         jax.lax.while_loop(cond, body, init))
     with jax.named_scope("asc.merge"):
         top_ids = jnp.where(top_scores > NEG, top_ids, -1)
@@ -804,7 +815,7 @@ def _search_batch(index: ClusterIndex, qmaps: jax.Array, seg_b: jax.Array,
         full = lambda v: jnp.full((n_q,), v, jnp.int32)
         out = (top_ids, top_scores, n_docs, n_clusters, n_segments,
                full(n_tiles_exec), full(n_tiles_walk), full(n_docs_walk),
-               full(g_end))
+               full(g_end), n_merge)
     return out + (rec,) if record_plans else out
 
 
@@ -901,7 +912,7 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
     def body(state):
         (w, done, top_scores, top_ids, n_docs, n_clusters, n_segments,
          n_pruned, n_tiles_exec, n_tiles_walk, n_docs_walk,
-         n_bounded, n_sup_walked) = state
+         n_bounded, n_sup_walked, n_merge) = state
         with jax.named_scope("asc.plan"):
             theta = top_scores[:, k - 1]                     # (n_q,)
             members = members_ord[w]                         # (cap,)
@@ -922,7 +933,7 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
 
         def heavy(args):
             (done, top_scores, top_ids, n_docs, n_clusters, n_segments,
-             n_pruned, n_tiles_exec, n_docs_walk) = args
+             n_pruned, n_tiles_exec, n_docs_walk, n_merge) = args
             with jax.named_scope("asc.bounds"):
                 # the survivors' share of the fine bound pass: one fused
                 # GEMM over this superblock's member rows only
@@ -966,9 +977,10 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
 
             with jax.named_scope("asc.merge"):
                 doc_admit = scores > NEG              # (n_q, cap, dp)
-                top_scores, top_ids = _merge_wave(
+                top_scores, top_ids, n_cand = _merge_wave(
                     index, plan.cids, scores, theta, top_scores, top_ids,
                     k)
+                n_merge += n_cand
                 n_docs += doc_admit.sum(axis=(1, 2)).astype(jnp.int32)
                 n_clusters += plan.admit.sum(axis=1).astype(jnp.int32)
                 n_segments += plan.seg_admit.sum(axis=(1, 2)).astype(
@@ -977,12 +989,12 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
                 n_docs_walk += plan.walked_docs()
                 return (done, top_scores, top_ids, n_docs, n_clusters,
                         n_segments, n_pruned, n_tiles_exec, n_docs_walk,
-                        glive.sum().astype(jnp.int32),
+                        n_merge, glive.sum().astype(jnp.int32),
                         jnp.int32(cap * n_qb))
 
         def skip(args):
             (done, top_scores, top_ids, n_docs, n_clusters, n_segments,
-             n_pruned, n_tiles_exec, n_docs_walk) = args
+             n_pruned, n_tiles_exec, n_docs_walk, n_merge) = args
             # every live member is pruned for every not-done query
             # (dominance) — pruned clusters inside the budget horizon
             # stay budget-free, exactly as _admission would count them
@@ -992,13 +1004,13 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
                 n_pruned += (live_q & gate).sum(axis=1).astype(jnp.int32)
             return (done, top_scores, top_ids, n_docs, n_clusters,
                     n_segments, n_pruned, n_tiles_exec, n_docs_walk,
-                    jnp.int32(0), jnp.int32(0))
+                    n_merge, jnp.int32(0), jnp.int32(0))
 
         args = (done, top_scores, top_ids, n_docs, n_clusters,
-                n_segments, n_pruned, n_tiles_exec, n_docs_walk)
+                n_segments, n_pruned, n_tiles_exec, n_docs_walk, n_merge)
         (done, top_scores, top_ids, n_docs, n_clusters, n_segments,
-         n_pruned, n_tiles_exec, n_docs_walk, bounded_w, walk_w) = (
-            jax.lax.cond(walked, heavy, skip, args))
+         n_pruned, n_tiles_exec, n_docs_walk, n_merge, bounded_w,
+         walk_w) = jax.lax.cond(walked, heavy, skip, args)
         with jax.named_scope("asc.merge"):
             n_bounded += bounded_w
             n_tiles_walk += walk_w
@@ -1013,17 +1025,17 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
                     | (n_clusters >= budget))
         return (w + 1, done, top_scores, top_ids, n_docs, n_clusters,
                 n_segments, n_pruned, n_tiles_exec, n_tiles_walk,
-                n_docs_walk, n_bounded, n_sup_walked)
+                n_docs_walk, n_bounded, n_sup_walked, n_merge)
 
     init = (jnp.int32(0), jnp.zeros((n_q,), bool),
             jnp.full((n_q, k), NEG), jnp.full((n_q, k), -1, jnp.int32),
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
             jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
-            jnp.int32(0))
+            jnp.int32(0), jnp.zeros((n_q,), jnp.int32))
     (w_end, _, top_scores, top_ids, n_docs, n_clusters, n_segments, _,
      n_tiles_exec, n_tiles_walk, n_docs_walk, n_bounded,
-     n_sup_walked) = jax.lax.while_loop(cond, body, init)
+     n_sup_walked, n_merge) = jax.lax.while_loop(cond, body, init)
     with jax.named_scope("asc.merge"):
         top_ids = jnp.where(top_scores > NEG, top_ids, -1)
         full = lambda v: jnp.full((n_q,), v, jnp.int32)
@@ -1031,7 +1043,7 @@ def _search_batch_super(index: ClusterIndex, qmaps: jax.Array,
         return (top_ids, top_scores, n_docs, n_clusters, n_segments,
                 full(n_tiles_exec), full(n_tiles_walk), full(n_docs_walk),
                 full(w_end), full(n_bounded), full(n_sup_walked),
-                full(jnp.int32(S) - n_sup_walked))
+                full(jnp.int32(S) - n_sup_walked), n_merge)
 
 
 def _method_stats(stats: dict, cfg: SearchConfig) -> tuple:
@@ -1060,7 +1072,9 @@ def _retrieve_arrays(index: ClusterIndex, queries: QueryBatch,
                      mu_eta: jax.Array | None = None) -> tuple:
     """Every TopK field in TopK's order, each leading n_q — plus the
     recorded wave plans as a trailing element when ``record_plans``
-    (batched engine only).
+    (batched engine only). The single-level walks return their own
+    fields with ``n_merge_candidates`` last; the degenerate level-0
+    funnel goes in before it.
 
     Shared by :func:`retrieve`, :func:`retrieve_with_plans` and the
     distributed shard-local search. The dense query maps are
@@ -1099,19 +1113,19 @@ def _retrieve_arrays(index: ClusterIndex, queries: QueryBatch,
             fn = jax.vmap(
                 lambda qmap, b, mx, av, key: _search_one_query(
                     index, qmap, b, mx, av, key, cfg, budget=budget))
-            return fn(qmaps, seg_b, max_s, avg_s, order_key) + degenerate
-        fn = jax.vmap(
-            lambda qmap, b, mx, av, key, me: _search_one_query(
-                index, qmap, b, mx, av, key, cfg, budget=budget,
-                mu_eta=me))
-        return (fn(qmaps, seg_b, max_s, avg_s, order_key, mu_eta)
-                + degenerate)
-    out = _search_batch(index, qmaps, seg_b, max_s, avg_s, order_key,
-                        cfg, budget=budget, record_plans=record_plans,
-                        mu_eta=mu_eta)
-    if record_plans:
-        return tuple(out[:-1]) + degenerate + (out[-1],)
-    return out + degenerate
+            out = fn(qmaps, seg_b, max_s, avg_s, order_key)
+        else:
+            fn = jax.vmap(
+                lambda qmap, b, mx, av, key, me: _search_one_query(
+                    index, qmap, b, mx, av, key, cfg, budget=budget,
+                    mu_eta=me))
+            out = fn(qmaps, seg_b, max_s, avg_s, order_key, mu_eta)
+    else:
+        out = _search_batch(index, qmaps, seg_b, max_s, avg_s, order_key,
+                            cfg, budget=budget, record_plans=record_plans,
+                            mu_eta=mu_eta)
+    # out[9] is n_merge_candidates, then the recorded plans if any
+    return tuple(out[:9]) + degenerate + tuple(out[9:])
 
 
 def _topk_of(arrays: tuple) -> TopK:
@@ -1314,7 +1328,7 @@ def _exec_fused(index: ClusterIndex, qmaps: jax.Array, plans: tuple,
     exit_div = jnp.float32(cfg.eta if cfg.method == "asc" else cfg.mu)
 
     (done, top_scores, top_ids, n_docs, n_clusters, n_segments, n_pruned,
-     n_tiles_exec, n_tiles_walk, n_docs_walk) = carry
+     n_tiles_exec, n_tiles_walk, n_docs_walk, n_merge) = carry
     w_tiles, w_blocks, w_pairs, w_segs, w_slots, w_on = [], [], [], [], [], []
 
     for f in range(F):
@@ -1340,8 +1354,8 @@ def _exec_fused(index: ClusterIndex, qmaps: jax.Array, plans: tuple,
             scores = jnp.where(mask_ex, raw, NEG)            # (n_q,G,dp)
 
         with jax.named_scope("asc.merge"):
-            new_ts, new_ti = _merge_wave(index, cids, scores, theta,
-                                         top_scores, top_ids, k)
+            new_ts, new_ti, n_cand = _merge_wave(index, cids, scores, theta,
+                                                 top_scores, top_ids, k)
             top_scores = jnp.where(wave_on, new_ts, top_scores)
             top_ids = jnp.where(wave_on, new_ti, top_ids)
 
@@ -1359,6 +1373,7 @@ def _exec_fused(index: ClusterIndex, qmaps: jax.Array, plans: tuple,
             n_tiles_exec = upd(n_tiles_exec, blocks_ex)
             n_tiles_walk = upd(n_tiles_walk, jnp.int32(G * n_qb))
             n_docs_walk = upd(n_docs_walk, slots_ex)
+            n_merge = upd(n_merge, n_cand)
 
             theta_new = top_scores[:, k - 1]
             remaining = jax.lax.dynamic_slice_in_dim(
@@ -1379,7 +1394,7 @@ def _exec_fused(index: ClusterIndex, qmaps: jax.Array, plans: tuple,
             w_on.append(wave_on)
 
     carry = (done, top_scores, top_ids, n_docs, n_clusters, n_segments,
-             n_pruned, n_tiles_exec, n_tiles_walk, n_docs_walk)
+             n_pruned, n_tiles_exec, n_tiles_walk, n_docs_walk, n_merge)
     stats = {"tiles": jnp.stack(w_tiles), "blocks": jnp.stack(w_blocks),
              "pairs": jnp.stack(w_pairs), "segments": jnp.stack(w_segs),
              "slots": jnp.stack(w_slots), "on": jnp.stack(w_on)}
@@ -1391,7 +1406,8 @@ def _pipeline_init_carry(n_q: int, k: int) -> tuple:
             jnp.full((n_q, k), NEG), jnp.full((n_q, k), -1, jnp.int32),
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
             jnp.zeros((n_q,), jnp.int32), jnp.zeros((n_q,), jnp.int32),
-            jnp.int32(0), jnp.int32(0), jnp.int32(0))
+            jnp.int32(0), jnp.int32(0), jnp.int32(0),
+            jnp.zeros((n_q,), jnp.int32))
 
 
 def _fuse_size(n: int) -> int:
@@ -1561,7 +1577,7 @@ def retrieve_pipelined(index: ClusterIndex, queries: QueryBatch,
     retire()
 
     (done, top_scores, top_ids, n_docs, n_clusters, n_segments, _,
-     n_tiles_exec, n_tiles_walk, n_docs_walk) = stale
+     n_tiles_exec, n_tiles_walk, n_docs_walk, n_merge) = stale
     top_ids = jnp.where(top_scores > NEG, top_ids, -1)
     full = lambda v: jnp.full((n_q,), v, jnp.int32)
     topk = TopK(doc_ids=top_ids, scores=top_scores, n_scored_docs=n_docs,
@@ -1572,7 +1588,8 @@ def retrieve_pipelined(index: ClusterIndex, queries: QueryBatch,
                 n_waves=full(n_waves),
                 n_bounded_clusters=full(m),
                 n_walked_superblocks=full(index.n_super),
-                n_pruned_superblocks=full(0))
+                n_pruned_superblocks=full(0),
+                n_merge_candidates=n_merge)
     if not with_info:
         return topk
     info = {

@@ -285,7 +285,8 @@ class ClusterIndex:
     data_fields=("doc_ids", "scores", "n_scored_docs", "n_scored_clusters",
                  "n_scored_segments", "n_scored_tiles", "n_walked_tiles",
                  "n_walked_docs", "n_waves", "n_bounded_clusters",
-                 "n_walked_superblocks", "n_pruned_superblocks"),
+                 "n_walked_superblocks", "n_pruned_superblocks",
+                 "n_merge_candidates"),
     meta_fields=(),
 )
 @dataclasses.dataclass(frozen=True)
@@ -333,6 +334,16 @@ class TopK:
     members of walked superblocks <= m``). Single-level engines report
     the degenerate funnel: bounded == m (one dense GEMM prices every
     cluster), walked == S, pruned == 0.
+    n_merge_candidates: (n_q,) int32 — a per-query counter (each query's
+    own, summed over queries like ``n_scored_docs``): the scored docs
+    whose score beat the query's theta (its running k-th score) at the
+    wave that scored them, and so entered that wave's top-k merge,
+    summed over waves — the merge load that a larger k adds. The
+    batched, two-level, per-query and pipelined walks count it on that
+    definition; the brute-force oracle counts the live docs it scores;
+    the distributed path sums its cluster shards' counts. Every
+    returned non-``-1`` id beat theta at its wave, so
+    ``n_merge_candidates >= (doc_ids >= 0).sum(axis=1)``.
     """
 
     doc_ids: jax.Array
@@ -347,6 +358,7 @@ class TopK:
     n_bounded_clusters: jax.Array
     n_walked_superblocks: jax.Array
     n_pruned_superblocks: jax.Array
+    n_merge_candidates: jax.Array
 
 
 def tree_bytes(tree: Any) -> int:

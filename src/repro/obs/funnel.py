@@ -62,6 +62,9 @@ AUX_COUNTERS = (
     ("clusters_bounded", "funnel_clusters_bounded_total",
      "clusters whose fine bound rows entered the bounds GEMM "
      "(members of walked superblocks; m on single-level engines)"),
+    ("merge_candidates", "funnel_merge_candidates_total",
+     "scored docs that beat their query's theta at their wave and "
+     "entered the top-k merge, summed over queries"),
 )
 
 
@@ -98,6 +101,8 @@ def funnel_from_topk(out, *, batched: bool, n_q: int, d_pad: int,
         "docs_scored": int(np.asarray(out.n_scored_docs).sum()),
         "clusters_scored": int(np.asarray(out.n_scored_clusters).sum()),
         "segments_scored": int(np.asarray(out.n_scored_segments).sum()),
+        # per-query on every engine, like docs_scored: summed
+        "merge_candidates": int(np.asarray(out.n_merge_candidates).sum()),
         # level-0 counters are batch-level on the batched engine
         # (replicated per query within each query shard, exactly like
         # the tile counters — the same one-slot-per-shard arithmetic

@@ -659,7 +659,7 @@ def _distributed_topk(index: ClusterIndex, queries: QueryBatch,
         # compacted work queues and executed exactly like the single-host
         # core — each local tile fetched once per batch, only if admitted)
         (ids, scores, nd, nc, ns, nt, nw, nwd, nwv,
-         nbc, nws, nps) = _retrieve_arrays(index_local, q_local, cfg)
+         nbc, nws, nps, nmc) = _retrieve_arrays(index_local, q_local, cfg)
         # merge the per-shard top-k across the cluster axes
         for ax in caxes:
             all_scores = jax.lax.all_gather(scores, ax, axis=1, tiled=True)
@@ -672,6 +672,9 @@ def _distributed_topk(index: ClusterIndex, queries: QueryBatch,
         nt = jax.lax.psum(nt, caxes)
         nw = jax.lax.psum(nw, caxes)
         nwd = jax.lax.psum(nwd, caxes)
+        # per query: each cluster shard counts the docs that beat
+        # its own theta, so the shards' counts add
+        nmc = jax.lax.psum(nmc, caxes)
         # clusters-bounded is per-shard work -> psum to the global m;
         # the superblock walk/prune counters are NOT psum'd: they count
         # against the *replicated* coarse table, so summing over cluster
@@ -685,7 +688,8 @@ def _distributed_topk(index: ClusterIndex, queries: QueryBatch,
                     n_scored_clusters=nc, n_scored_segments=ns,
                     n_scored_tiles=nt, n_walked_tiles=nw,
                     n_walked_docs=nwd, n_waves=nwv, n_bounded_clusters=nbc,
-                    n_walked_superblocks=nws, n_pruned_superblocks=nps)
+                    n_walked_superblocks=nws, n_pruned_superblocks=nps,
+                    n_merge_candidates=nmc)
 
     out_specs = TopK(doc_ids=P(qaxis, None), scores=P(qaxis, None),
                      n_scored_docs=P(qaxis), n_scored_clusters=P(qaxis),
@@ -693,7 +697,8 @@ def _distributed_topk(index: ClusterIndex, queries: QueryBatch,
                      n_scored_tiles=P(qaxis), n_walked_docs=P(qaxis),
                      n_waves=P(qaxis), n_bounded_clusters=P(qaxis),
                      n_walked_superblocks=P(qaxis),
-                     n_pruned_superblocks=P(qaxis))
+                     n_pruned_superblocks=P(qaxis),
+                     n_merge_candidates=P(qaxis))
     fn = jax.shard_map(local, mesh=mesh, in_specs=(ispecs, qspec),
                        out_specs=out_specs, check_vma=False)
     return fn(index, queries)

@@ -512,6 +512,7 @@ def test_no_hang_property(engine, rows, ops, fault_pt, fault_action,
     base.warmup(rows[0])
     submitted_before = base._m_submitted.value
     futs = []
+    raised = 0      # submits whose overflow 'raise' reached the caller
     with install(FaultSchedule([(fault_pt, fault_nth, fault_action)])):
         for v in ops:
             op = v % 4
@@ -526,7 +527,9 @@ def test_no_hang_property(engine, rows, ops, fault_pt, fault_action,
                 else:
                     base.pump()
             except FaultInjected:
-                pass                            # overflow 'raise' action
+                # overflow 'raise' action: the request was counted and
+                # rejected before the raise, so it has no future
+                raised += op <= 1
         base.shutdown(drain_deadline_ms=1e4)
     for f in futs:
         assert f.done(), "a request future hung"
@@ -534,7 +537,7 @@ def test_no_hang_property(engine, rows, ops, fault_pt, fault_action,
                           (ServedResult, Rejected, DeadlineExceeded))
     cons = base.conservation()
     assert cons["balanced"], cons
-    assert base._m_submitted.value - submitted_before == len(futs)
+    assert base._m_submitted.value - submitted_before == len(futs) + raised
 
 
 # ---------------------------------------------------------------------------

@@ -201,7 +201,9 @@ def test_funnel_counters_match_engine(index, queries, engine):
                       ("tiles_walked", "funnel_tiles_walked_total"),
                       ("tiles_scored", "funnel_tiles_scored_total"),
                       ("doc_slots_walked", "funnel_doc_slots_walked_total"),
-                      ("docs_scored", "funnel_docs_scored_total")):
+                      ("docs_scored", "funnel_docs_scored_total"),
+                      ("merge_candidates",
+                       "funnel_merge_candidates_total")):
         got = obs.registry.get(name).value
         assert got == expect[key], (name, got, expect[key])
     # serve accounting agrees with the engine's stats object
@@ -241,6 +243,7 @@ def test_funnel_from_topk_sums_one_slot_per_query_shard():
         n_scored_docs=np.arange(8),
         n_scored_clusters=np.ones(8, np.int64),
         n_scored_segments=np.ones(8, np.int64),
+        n_merge_candidates=np.arange(8) * 3,
         # level-0 counters are batch-level too (ISSUE 9): same
         # one-representative-slot-per-shard arithmetic as the tile
         # counters, same undercount if slot [0] were used alone
@@ -254,6 +257,8 @@ def test_funnel_from_topk_sums_one_slot_per_query_shard():
     assert f["doc_slots_walked"] == 30 + 20
     assert f["waves"] == 6 + 4
     assert f["docs_scored"] == int(np.arange(8).sum())
+    # per-query on every engine: summed over all slots
+    assert f["merge_candidates"] == 3 * int(np.arange(8).sum())
     assert f["superblocks_walked"] == 4 + 3
     assert f["superblocks_pruned"] == 2 + 3
     assert f["clusters_bounded"] == 9 + 6

@@ -88,11 +88,10 @@ def test_compact_front_pallas_compiles(one_chip, rows, n):
 _BATCH = 64
 
 
-@pytest.fixture(scope="module")
-def batched_step(one_chip):
+def _served_step(one_chip, k: int):
     """The served step (``engine="batched"``) at SPLADE widths, m=16,
-    batch 64, with the front-end's per-request (mu, eta), compiled once
-    for the tests that read it."""
+    batch 64, top-``k``, with the front-end's per-request (mu, eta):
+    (cfg, (compiled, text))."""
     from repro.core.search import SearchConfig, retrieve
     from repro.core.types import ClusterIndex, QueryBatch
     m, n_seg, dp, tp, v, b = (16, SPLADE.n_seg, SPLADE.d_pad, SPLADE.t_pad,
@@ -115,11 +114,17 @@ def batched_step(one_chip):
     queries = QueryBatch(tids=s((b, SPLADE.q_pad), jnp.int32),
                          tw=s((b, SPLADE.q_pad), jnp.float32),
                          mask=s((b, SPLADE.q_pad), jnp.bool_), vocab=v)
-    cfg = SearchConfig(k=SPLADE.k, mu=SPLADE.mu, eta=SPLADE.eta,
-                       engine="batched")
+    cfg = SearchConfig(k=k, mu=SPLADE.mu, eta=SPLADE.eta, engine="batched")
     return cfg, _compile(
         lambda i, q, me: retrieve(i, q, cfg, mu_eta=me), index, queries,
         s((b, 2), jnp.float32))
+
+
+@pytest.fixture(scope="module")
+def batched_step(one_chip):
+    """The served step at the configuration's k, compiled once for the
+    tests that read it."""
+    return _served_step(one_chip, SPLADE.k)
 
 
 def test_batched_retrieve_step_compiles(batched_step):
@@ -150,3 +155,12 @@ def test_batched_step_looks_up_segments_without_a_gather(batched_step):
     for n, sizes, line in gathers:
         assert n != _BATCH * wave_slots, line
         assert not (n % wave_slots == 0 and sizes == {1}), line
+
+
+def test_batched_step_compiles_at_k1000(one_chip):
+    """The served step at k=1000 (``msmarco-splade-shard8-k1000``):
+    each wave's top-1,000 of G·d_pad = 20,480 candidates and the
+    2,000 -> 1,000 merge compile for a v5e, in about the k=10 step's
+    memory (the running top-k is 64 x 1,000)."""
+    _, (compiled, _) = _served_step(one_chip, 1000)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
